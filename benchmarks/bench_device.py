@@ -181,6 +181,9 @@ def run(n: int = N, n_queries: int = NQ, error: int = ERROR,
                   device_counts=tuple(device_counts), slack=slack,
                   inserts=inserts)
     env = dict(os.environ)
+    # a forced-host-device simulation by design: the child never reaches for
+    # an accelerator (the parent may already hold it -- one process per chip)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{max(device_counts)}")
     env["REPRO_SANITIZE"] = "0"          # measuring, not debugging

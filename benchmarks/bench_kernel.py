@@ -24,7 +24,7 @@ def run():
     rows = []
     for e in (16, 64, 256):
         idx = build_device_index(keys, e)
-        got = np.asarray(fitting_lookup(idx, q[:512], interpret=True))
+        got = np.asarray(fitting_lookup(idx, q[:512]))
         want = np.asarray(lookup_ref(idx.keys, q[:512]))
         assert np.array_equal(got, want), "kernel != oracle"
         f_win = jax.jit(lambda qq, i=idx: lookup(i, qq, "window"))

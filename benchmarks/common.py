@@ -7,7 +7,11 @@ import pathlib
 import time
 from typing import Iterable
 
+from repro.compile_cache import enable_compile_cache
+
 OUT_DIR = pathlib.Path(__file__).parent / "out"
+
+enable_compile_cache()
 
 
 def _jsonable(obj):

@@ -142,6 +142,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.index import SegmentTable, available_backends, make_engine, plan
 from repro.kernels.ref import lookup_ref
 from repro.serve import (AsyncIndexService, FitSpec, IndexService, Monitor,
@@ -161,6 +162,7 @@ def main():
     ap.add_argument("--skew-threshold", type=float, default=1.5)
     ap.add_argument("--distributed", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     keys = np.sort(rng.choice(2 ** 23, size=args.n, replace=False)).astype(

@@ -107,7 +107,6 @@ HOST_ONLY_MODULES = (
 # Import roots that pull jax in at module scope (transitively included).
 ACCEL_IMPORT_ROOTS = (
     "jax", "jaxlib",
-    "repro.compat",
     "repro.kernels", "repro.models",
     "repro.index.engine", "repro.index.snapshot", "repro.index.sharded",
     "repro.index.pipeline", "repro.index.fit", "repro.index.lsm",
@@ -142,7 +141,6 @@ LOCK_ORDER = (
     "ServingHandle._lock",               # per-shard install swap
     "DispatchEngine._lock",              # lazy tier-engine build
     "DeviceShardedService._fn_lock",     # lazy collective-kernel build
-    "_DeviceEngine._search_lock",        # lazy search-kernel build
     "Monitor._make_lock",                # channel-ring creation
     "JSONLBackend._io_lock",             # telemetry sink flush
     "DeviceShardedService._counts_lock",  # device verb counters

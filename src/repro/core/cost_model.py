@@ -27,12 +27,19 @@ class CostParams:
 
 @dataclasses.dataclass(frozen=True)
 class TPUCostParams:
+    """TPU v5e's figures (Google Cloud, "TPU v5e": 819 GB/s HBM); planning
+    for another TPU with these defaults is refused (``fit.plan``)."""
     hbm_gbps: float = 819.0   # v5e HBM bandwidth
     dma_setup_ns: float = 600.0   # fixed DMA issue latency
     vmem_step_ns: float = 3.0     # per router level probe in VMEM
     bytes_per_key: int = 8
     launch_ns: float = 25_000.0   # host->device dispatch of one jitted call
     plan_ns: float = 75_000.0     # Pallas prelude: bucketing argsort + scatter
+
+
+# ``device_kind`` strings, as JAX reports them, of the chip whose figures the
+# TPUCostParams defaults hold
+TPU_COST_PARAMS_KINDS = ("TPU v5 lite", "TPU v5e")
 
 
 def latency_ns(error: int, n_segments: int, p: CostParams) -> float:

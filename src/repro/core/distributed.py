@@ -89,9 +89,10 @@ def lookup_allgather(si: ShardedIndex, queries: jax.Array, mesh: Mesh,
     :func:`repro.index.device.sharded_lookup_allgather` (use
     ``DeviceShardedService`` for the served plane)."""
     n_local, _ = _seed_layout(si, mesh.shape[axis])
-    return sharded_lookup_allgather(
-        si.seg_start, si.slope, si.base, si.seg_end, si.keys, n_local,
-        queries, mesh=mesh, axis=axis, error=si.error)
+    with jax.set_mesh(mesh):    # eager shard_map over an Explicit-axis mesh
+        return sharded_lookup_allgather(
+            si.seg_start, si.slope, si.base, si.seg_end, si.keys, n_local,
+            queries, mesh=mesh, axis=axis, error=si.error)
 
 
 def lookup_a2a(si: ShardedIndex, queries: jax.Array, mesh: Mesh,
@@ -106,7 +107,8 @@ def lookup_a2a(si: ShardedIndex, queries: jax.Array, mesh: Mesh,
     ``DeviceShardedService`` performs that follow-up pass itself, so the
     mask never reaches *its* callers."""
     n_local, offsets = _seed_layout(si, mesh.shape[axis])
-    return sharded_lookup_a2a(
-        si.seg_start, si.slope, si.base, si.seg_end, si.keys, n_local,
-        offsets, si.boundaries, queries, mesh=mesh, axis=axis,
-        error=si.error, slack=slack)
+    with jax.set_mesh(mesh):    # eager shard_map over an Explicit-axis mesh
+        return sharded_lookup_a2a(
+            si.seg_start, si.slope, si.base, si.seg_end, si.keys, n_local,
+            offsets, si.boundaries, queries, mesh=mesh, axis=axis,
+            error=si.error, slack=slack)

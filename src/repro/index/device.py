@@ -58,7 +58,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analysis import sanitizer
-from repro.compat import shard_map as _shard_map
 from repro.core.cost_model import choose_exchange
 from repro.index.table import route_keys
 
@@ -89,7 +88,7 @@ def sharded_search_allgather(seg_start, slope, base, seg_end, keys, n_local,
     a shard cut included (a sum needs no ownership decision).  Padded +inf
     keys are never counted for finite queries, so capacity padding is
     invisible to the answer."""
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis, None), P(axis, None), P(axis, None),
                        P(axis, None), P(axis, None), P(axis), P(axis)),
              out_specs=P(axis))
@@ -126,7 +125,7 @@ def sharded_search_a2a(seg_start, slope, base, seg_end, keys, n_local,
     q_per = queries.shape[0] // d
     cap = max(1, int(np.ceil(q_per / d * slack)))
 
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis, None), P(axis, None), P(axis, None),
                        P(axis, None), P(axis, None), P(axis), P(), P(),
                        P(axis)),

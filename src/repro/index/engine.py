@@ -8,6 +8,7 @@ sharded serving).  It now exists exactly once per backend, behind a registry:
     xla-window  gather the 2e+2 window and compare-reduce (VPU friendly)
     xla-bisect  log2(2e) halving steps of single gathers (fewer bytes, big e)
     pallas      bucketed compare-reduce TPU kernel with XLA-bisect fallback
+                (compiled by Mosaic on TPU, interpreted on the CPU backend)
 
 ``make_engine(table, backend=...)`` returns an engine whose ``lookup`` maps a
 query batch to global ranks (-1 if absent; the *leftmost* rank for duplicated
@@ -43,6 +44,12 @@ from .query import QueryVerbs
 from .table import SegmentTable, numpy_lookup, numpy_search
 
 
+def _bucket_size(n: int) -> int:
+    """The power-of-two batch bucket ``n`` pads into (floor 16, so tiny
+    batches share a handful of shapes instead of one each)."""
+    return max(16, 1 << (int(n) - 1).bit_length())
+
+
 class DeviceIndex(NamedTuple):
     """f32/i32 device form of a SegmentTable (arrays VMEM/HBM friendly)."""
     seg_start: jax.Array  # (S,) f32  first key of each segment
@@ -57,12 +64,16 @@ def device_index(table: SegmentTable) -> DeviceIndex:
     """Convert (and cache on the table -- snapshots are shared by engines)."""
     dev = getattr(table, "_device_cache", None)
     if dev is None:
+        # cast on the host: a device-side cast compiles once per shape
+        def put(a, dtype):
+            return jax.device_put(np.asarray(a, dtype))
+
         dev = DeviceIndex(
-            seg_start=jnp.asarray(table.start_key, jnp.float32),
-            slope=jnp.asarray(table.slope, jnp.float32),
-            base=jnp.asarray(table.base, jnp.int32),
-            seg_end=jnp.asarray(table.seg_end, jnp.int32),
-            keys=jnp.asarray(table.keys, jnp.float32),
+            seg_start=put(table.start_key, np.float32),
+            slope=put(table.slope, np.float32),
+            base=put(table.base, np.int32),
+            seg_end=put(table.seg_end, np.int32),
+            keys=put(table.keys, np.float32),
             error=int(table.error),
         )
         object.__setattr__(table, "_device_cache", dev)  # frozen dataclass
@@ -213,9 +224,13 @@ class LookupPlan(NamedTuple):
 
 
 def make_plan(n_keys: int, error: int) -> LookupPlan:
+    # lazy: repro.kernels imports this module for its thin wrappers
+    from repro.kernels.fitting_lookup import ROWS
+
     window = 2 * error + 2
     kb = max(128, _round_up(window, 128))
-    n_pad = _round_up(max(n_keys, kb), kb)
+    # whole kernel grid steps: ROWS key blocks each
+    n_pad = _round_up(max(n_keys, kb), ROWS * kb)
     return LookupPlan(kb=kb, window=window, n_blocks=n_pad // kb, n_pad=n_pad)
 
 
@@ -252,13 +267,13 @@ def _pallas_bucketize(idx: DeviceIndex, queries: jax.Array, plan: LookupPlan,
 
 
 def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
-                  interpret: bool = True, fallback: bool = True) -> jax.Array:
+                  fallback: bool = True) -> jax.Array:
     """Batched point lookup via the Pallas kernel.  Returns ranks (-1 absent).
 
     XLA prelude (router + interpolation + bucketing) -> Pallas compare-reduce
     kernel -> scatter-back + bisect fallback for bucket overflow.  ``idx.error``
-    must be a Python int (it sizes the kernel window), so jit this via a
-    closure over ``idx`` rather than passing it as a traced argument."""
+    must be a Python int (it sizes the kernel window): jit this with the
+    index arrays as arguments and ``error`` static, as the engines do."""
     # lazy: repro.kernels imports this module for its thin wrappers
     from repro.kernels.fitting_lookup import fitting_lookup_pallas
 
@@ -270,8 +285,7 @@ def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
 
     # --- Pallas kernel over key blocks
     rank_b, found_b = fitting_lookup_pallas(
-        keys_padded, q_b, qlo_b, kb=plan.kb, window=plan.window,
-        interpret=interpret)
+        keys_padded, q_b, qlo_b, kb=plan.kb, window=plan.window)
 
     # --- scatter back
     res = jnp.full((nq,), jnp.iinfo(jnp.int32).min, jnp.int32)
@@ -296,7 +310,7 @@ def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
 
 
 def pallas_search(idx: DeviceIndex, queries: jax.Array, side: str = "left", *,
-                  qcap: int = 256, interpret: bool = True) -> jax.Array:
+                  qcap: int = 256) -> jax.Array:
     """Batched insertion-rank search via the Pallas compare-reduce kernel.
 
     Same XLA prelude (router + interpolation + bucketing) and kernel geometry
@@ -317,8 +331,7 @@ def pallas_search(idx: DeviceIndex, queries: jax.Array, side: str = "left", *,
     q_b, qlo_b, src_b = _pallas_bucketize(idx, queries, plan, qcap)
 
     rank_b, _ = fitting_lookup_pallas(
-        keys_padded, q_b, qlo_b, kb=plan.kb, window=plan.window,
-        interpret=interpret, side=side)
+        keys_padded, q_b, qlo_b, kb=plan.kb, window=plan.window, side=side)
 
     res = jnp.full((nq,), jnp.iinfo(jnp.int32).min, jnp.int32)
     flat_src = src_b.reshape(-1)
@@ -406,45 +419,67 @@ class NumpyEngine(QueryVerbs):
         """No-op: the host path has nothing to compile."""
 
 
+@functools.partial(jax.jit, static_argnames=("impl", "error", "opts"))
+def _run_on_index(arrays, queries, *, impl, error, opts):
+    """The one jitted entry of every device engine: ``impl(DeviceIndex,
+    queries, **opts)`` with the table's arrays as *arguments*, so an
+    executable serves every table of the same shapes instead of carrying the
+    key column as a constant.  ``error`` sizes the window and ``opts``
+    (sorted ``(name, value)`` pairs: strategy, side, qcap) pick the code
+    path, so both are static."""
+    return impl(DeviceIndex(*arrays, error), queries, **dict(opts))
+
+
 class _DeviceEngine(QueryVerbs):
-    """Shared scaffolding: convert the table once, jit a closure over it.
+    """Shared scaffolding: convert the table once, run the backend's
+    ``_lookup_impl`` / ``_search_impl`` through :func:`_run_on_index`.
 
-    ``self.fn`` is the jitted point-lookup; ``_search_impl(queries, side=)``
-    is the backend's un-jitted search primitive, jitted lazily per side on
-    first use (``side`` is static: it picks the comparison op)."""
+    Each batch is padded to its power-of-two bucket (:func:`_bucket_size`;
+    padding lanes repeat the batch, so they spread over the key blocks like
+    the real queries) and the tail is sliced off: a sharded service splits
+    every batch by routing, and without the bucket each distinct per-shard
+    size would be a fresh compile."""
 
-    def __init__(self, table: SegmentTable):
+    _lookup_impl: Callable
+    _search_impl: Callable
+
+    def __init__(self, table: SegmentTable, lookup_opts: dict | None = None,
+                 search_opts: dict | None = None):
         self.table = table
         self.index = device_index(table)
-        self._search_fns: dict[str, Callable] = {}
-        self._search_lock = make_lock("_DeviceEngine._search_lock")
+        self._arrays = tuple(self.index)[:5]
+        self._lookup_opts = tuple(sorted((lookup_opts or {}).items()))
+        self._search_opts = dict(search_opts or {})
+
+    def _run(self, impl, queries, opts) -> np.ndarray:
+        q = np.asarray(queries, np.float32)
+        flat = q.ravel()
+        n = flat.size
+        if n:
+            flat = np.resize(flat, _bucket_size(n))
+        out = _run_on_index(self._arrays, jnp.asarray(flat), impl=impl,
+                            error=self.index.error, opts=opts)
+        return np.asarray(out)[:n].reshape(q.shape)
 
     def lookup(self, queries) -> np.ndarray:
         if self.table.n_keys == 0:   # gathers on a 0-length device array are
             q = np.asarray(queries)  # undefined; an empty table always misses
             return np.full(q.shape, -1, np.int64)
-        return np.asarray(self.fn(jnp.asarray(queries, jnp.float32)))
+        return self._run(type(self)._lookup_impl, queries, self._lookup_opts)
 
     def search(self, queries, side: str = "left") -> np.ndarray:
         if side not in ("left", "right"):
             raise ValueError(f"side must be 'left' or 'right', got {side!r}")
         if self.table.n_keys == 0:   # empty table: every rank is 0
             return np.zeros(np.asarray(queries).shape, np.int64)
-        fn = self._search_fns.get(side)
-        if fn is None:
-            with self._search_lock:  # don't jit the same side twice
-                fn = self._search_fns.get(side)
-                if fn is None:
-                    fn = jax.jit(functools.partial(self._search_impl,
-                                                   side=side))
-                    self._search_fns[side] = fn
-        out = np.asarray(fn(jnp.asarray(queries, jnp.float32)))
+        opts = tuple(sorted({**self._search_opts, "side": side}.items()))
+        out = self._run(type(self)._search_impl, queries, opts)
         return out.astype(np.int64)
 
     def prewarm(self, batch_sizes=None) -> None:
         """Trace + compile the lookup and both search sides now, at the
         given batch sizes (jit caches are shape-specialized: a compile only
-        helps batches of the same size).  Default one representative size."""
+        helps batches of the same bucket).  Default one representative size."""
         if self.table.n_keys == 0:
             return
         for size in batch_sizes or (256,):
@@ -456,34 +491,31 @@ class _DeviceEngine(QueryVerbs):
 
 @register_backend("xla-window")
 class XlaWindowEngine(_DeviceEngine):
+    _lookup_impl = xla_lookup
+    _search_impl = xla_search
+
     def __init__(self, table: SegmentTable):
-        super().__init__(table)
-        self.fn = jax.jit(functools.partial(xla_lookup, self.index,
-                                            strategy="window"))
-        self._search_impl = functools.partial(xla_search, self.index,
-                                              strategy="window")
+        super().__init__(table, {"strategy": "window"}, {"strategy": "window"})
 
 
 @register_backend("xla-bisect")
 class XlaBisectEngine(_DeviceEngine):
+    _lookup_impl = xla_lookup
+    _search_impl = xla_search
+
     def __init__(self, table: SegmentTable):
-        super().__init__(table)
-        self.fn = jax.jit(functools.partial(xla_lookup, self.index,
-                                            strategy="bisect"))
-        self._search_impl = functools.partial(xla_search, self.index,
-                                              strategy="bisect")
+        super().__init__(table, {"strategy": "bisect"}, {"strategy": "bisect"})
 
 
 @register_backend("pallas")
 class PallasEngine(_DeviceEngine):
+    _lookup_impl = pallas_lookup
+    _search_impl = pallas_search
+
     def __init__(self, table: SegmentTable, *, qcap: int = 256,
-                 interpret: bool = True, fallback: bool = True):
-        super().__init__(table)
-        self.fn = jax.jit(functools.partial(pallas_lookup, self.index,
-                                            qcap=qcap, interpret=interpret,
-                                            fallback=fallback))
-        self._search_impl = functools.partial(pallas_search, self.index,
-                                              qcap=qcap, interpret=interpret)
+                 fallback: bool = True):
+        super().__init__(table, {"qcap": qcap, "fallback": fallback},
+                         {"qcap": qcap})
 
 
 @register_backend("dispatch")

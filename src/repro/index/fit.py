@@ -40,10 +40,12 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
+import sys
 
 import numpy as np
 
-from repro.core.cost_model import (CostParams, TPUCostParams, choose_exchange,
+from repro.core.cost_model import (TPU_COST_PARAMS_KINDS, CostParams,
+                                   TPUCostParams, choose_exchange,
                                    choose_error_for_latency,
                                    choose_error_for_space,
                                    dispatch_thresholds,
@@ -665,6 +667,29 @@ def _plan_backend(spec: FitSpec, small_max: int, large_min: int) -> str:
     return "dispatch"
 
 
+def _attached_tpu_kind() -> str | None:
+    """``device_kind`` of the TPU this process runs on; None without a TPU,
+    or when the process does not use jax (planning never imports it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    dev = jax.devices()[0]
+    return dev.device_kind if dev.platform == "tpu" else None
+
+
+def _check_tpu_params(spec: FitSpec) -> None:
+    """Refuse to plan a TPU with another chip's figures: the default
+    :class:`TPUCostParams` hold v5e's, so on any other TPU the caller must
+    pass measured ``tpu_params``.  CPU-side (offline) planning is free."""
+    if spec.tpu_params != TPUCostParams():
+        return
+    kind = _attached_tpu_kind()
+    if kind is not None and kind not in TPU_COST_PARAMS_KINDS:
+        raise ValueError(
+            f"hardware='tpu' plans with TPU v5e figures, but this process "
+            f"runs on a {kind!r}; pass tpu_params measured on this chip")
+
+
 def plan(keys, spec: FitSpec, *, assume_sorted: bool = False) -> IndexPlan:
     """Resolve a :class:`FitSpec` against ``keys`` (or the spec's own
     ``key_sample``) into a concrete :class:`IndexPlan`.
@@ -680,6 +705,8 @@ def plan(keys, spec: FitSpec, *, assume_sorted: bool = False) -> IndexPlan:
     ``assume_sorted=True`` skips the sort-copy of ``keys`` (results are
     garbage if they are not actually sorted).
     """
+    if spec.hardware == "tpu":
+        _check_tpu_params(spec)
     arr = _resolve_keys(keys, spec, assume_sorted)
     cands = tuple(sorted(set(int(e) for e in spec.candidate_errors)))
     if spec.error is not None and spec.error not in cands:

@@ -145,7 +145,6 @@ class AsyncIndexService:
                  queue_depth: int | None = None,
                  publish_interval_s: float | None = None,
                  backend: str | None = None,
-                 pad_batches: bool = True,
                  prewarm: bool = True,
                  monitor: Monitor | None = None,
                  replanner: Replanner | None = None):
@@ -197,7 +196,6 @@ class AsyncIndexService:
         self.queue_depth = int(queue_depth)
         self.publish_interval_s = publish_interval_s
         self.backend = backend
-        self.pad_batches = bool(pad_batches)
 
         # queue state: per-verb buckets so each flush fuses like with like
         # ("lookup" and each ("search", side) fuse separately -- a fused call
@@ -305,24 +303,14 @@ class AsyncIndexService:
 
     # --------------------------------------------------------------- the flush
     def _run(self, kind: tuple, fused: np.ndarray) -> np.ndarray:
-        """One fused service call.  ``pad_batches`` pads the fused batch to
-        its power-of-two bucket (repeating the first query; the tail is
-        sliced off) so the device backends see a *bounded set of shapes* --
-        without it every distinct flush size is a fresh jit compile and
-        prewarming could never cover the steady state."""
-        n = fused.shape[0]
-        if self.pad_batches:
-            m = _bucket_size(n)
-            if m > n:
-                fused = np.concatenate(
-                    [fused, np.full(m - n, fused[0], np.float64)])
+        """One fused service call.  The device engines pad every batch they
+        see to its power-of-two bucket, so flushes of any size reach a
+        bounded set of compiled shapes."""
         if kind[0] == "lookup":
-            out = np.asarray(self.service.lookup(fused, self.backend),
-                             np.int64)
-        else:
-            out = np.asarray(self.service.search(fused, kind[1], self.backend),
-                             np.int64)
-        return out[:n]
+            return np.asarray(self.service.lookup(fused, self.backend),
+                              np.int64)
+        return np.asarray(self.service.search(fused, kind[1], self.backend),
+                          np.int64)
 
     def _take_batches(self) -> list[tuple[tuple, list[_Request]]]:
         """Under _lock: claim everything queued and reset the queue."""
@@ -449,12 +437,10 @@ class AsyncIndexService:
         """Build and compile the serving engines before taking traffic (see
         ``ShardedIndexService.prewarm`` / ``DispatchEngine.prewarm``): the
         first coalesced flush then skips the jit/plan latency spike.
-        Compilation happens at the threshold's batch bucket -- the exact
-        shape a threshold flush dispatches (``pad_batches`` keeps the shape
-        set bounded, so this one compile covers the steady state)."""
-        sizes = (_bucket_size(self.flush_threshold),) if self.pad_batches \
-            else (self.flush_threshold,)
-        self.service.prewarm(backend or self.backend, batch_sizes=sizes)
+        Compilation happens at the threshold's batch bucket -- the shape a
+        threshold flush dispatches."""
+        self.service.prewarm(backend or self.backend,
+                             batch_sizes=(self.flush_threshold,))
 
     def publish(self):
         """Manual publish passthrough (the cadence thread's tick, on demand)."""
@@ -601,12 +587,6 @@ class AsyncIndexService:
                 "pending_inserts": m.pending_inserts,
                 "query_counts": m.query_counts,
                 "pipeline": dataclasses.asdict(m.pipeline)}
-
-
-def _bucket_size(n: int) -> int:
-    """The power-of-two batch bucket ``n`` pads into (floor 16, so tiny
-    deadline flushes share a handful of shapes instead of one each)."""
-    return max(16, 1 << (int(n) - 1).bit_length())
 
 
 def _plan_publish_interval(plan) -> float | None:

@@ -51,8 +51,10 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import os
 import time
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from typing import TYPE_CHECKING, NamedTuple, Sequence
 
 import numpy as np
@@ -449,17 +451,25 @@ class ShardedIndexService:
         ``backend`` before serving traffic -- called by the async pipeline on
         start so the first coalesced batch skips the lazy plan/compile spike.
         ``batch_sizes`` are the batch shapes to compile at (jit caches are
-        shape-specialized); with several shards a fused batch splits by
-        routing, so the per-shard shapes are exact only for one shard --
-        prewarm then still pays the per-tier compile for the common shapes.
-        Engines without a ``prewarm`` (custom registered backends) are just
-        built."""
+        shape-specialized; device engines pad each routed per-shard batch to
+        its power-of-two bucket, so a bucket compiled here serves every
+        nearby per-shard size).  Shards have tables of their own shapes and
+        compile separately; compilation releases the GIL, so the shards warm
+        side by side.  Engines without a ``prewarm`` (custom registered
+        backends) are just built."""
         backend = backend or self.default_backend
-        for handle in self._shard_set.handles:
-            eng = handle.engine(backend)
-            warm = getattr(eng, "prewarm", None)
-            if warm is not None:
+        engines = [h.engine(backend) for h in self._shard_set.handles]
+        warms = [w for w in (getattr(e, "prewarm", None) for e in engines)
+                 if w is not None]
+        if len(warms) <= 1:
+            for warm in warms:
                 warm(batch_sizes=batch_sizes)
+            return
+        workers = min(len(warms), os.cpu_count() or 1)
+        with ThreadPoolExecutor(workers, "index-prewarm") as pool:
+            for done in [pool.submit(w, batch_sizes=batch_sizes)
+                         for w in warms]:
+                done.result()
 
     # ------------------------------------------------------------- write path
     def insert(self, key: float, value=None) -> None:

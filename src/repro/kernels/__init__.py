@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (validated with interpret=True on CPU; TPU is the target).
+"""Pallas TPU kernels: compiled by Mosaic on TPU, interpreted on the CPU backend
+(``platform.pallas_call``), where the tests validate them.
 
 fitting_lookup -- the paper's hot path: batched learned-index probes
 flash_attention -- blocked online-softmax attention (serving path)

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -70,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     softcap: float | None = None, scale: float | None = None,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+                    bq: int = 128, bk: int = 128):
     """q: (B, H, Tq, hd); k, v: (B, Hkv, S, hd) with H % Hkv == 0.
 
     Returns (B, H, Tq, hd).  Query positions are aligned to the END of the
@@ -103,7 +105,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, bq=bq, bk=bk, seq_k=s, q_offset=s - tq)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((1, bq, hd), q_map),
@@ -116,6 +118,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
             pltpu.VMEM((bq, 1), jnp.float32),    # m (running max)
             pltpu.VMEM((bq, 1), jnp.float32),    # l (running denom)
         ],
-        interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, tq_p, hd)[:, :, :tq]

@@ -22,19 +22,21 @@ __all__ = ["LookupPlan", "make_plan", "pad_keys", "fitting_lookup",
            "make_lookup_fn"]
 
 
-def make_lookup_fn(idx: DeviceIndex, *, qcap: int = 256, interpret: bool = True,
+def make_lookup_fn(idx: DeviceIndex, *, qcap: int = 256,
                    fallback: bool = True):
-    """jit-compiled lookup closure over a fixed index (the serving path)."""
-    return jax.jit(functools.partial(fitting_lookup, idx, qcap=qcap,
-                                     interpret=interpret, fallback=fallback))
+    """jit-compiled lookup over a fixed index: the index arrays are passed
+    as arguments (``error`` is static), not baked in as constants."""
+    arrays, error = tuple(idx)[:5], int(idx.error)
+    fn = jax.jit(lambda arrays, q: fitting_lookup(
+        DeviceIndex(*arrays, error), q, qcap=qcap, fallback=fallback))
+    return functools.partial(fn, arrays)
 
 
 def fitting_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
-                   interpret: bool = True, fallback: bool = True) -> jax.Array:
+                   fallback: bool = True) -> jax.Array:
     """Batched point lookup via the Pallas kernel.  Returns ranks (-1 absent).
 
     ``idx.error`` must be a Python int (it sizes the kernel window), so jit
-    this via ``make_lookup_fn`` (closure) rather than passing idx as a traced
+    this via ``make_lookup_fn`` rather than passing idx as one traced
     argument."""
-    return pallas_lookup(idx, queries, qcap=qcap, interpret=interpret,
-                         fallback=fallback)
+    return pallas_lookup(idx, queries, qcap=qcap, fallback=fallback)

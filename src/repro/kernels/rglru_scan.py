@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import pallas_call
+
 
 def _rglru_kernel(a_ref, u_ref, h0_ref, out_ref, hT_ref, *, t: int):
     h = h0_ref[0, :]                             # (bw,)
@@ -35,7 +37,7 @@ def _rglru_kernel(a_ref, u_ref, h0_ref, out_ref, hT_ref, *, t: int):
 
 
 def rglru_scan_pallas(u: jax.Array, a: jax.Array, h0: jax.Array | None = None,
-                      *, bw: int = 128, interpret: bool = True):
+                      *, bw: int = 128):
     """u, a: (B, T, W) f32; h0: (B, W) initial state.  Returns (h, h_last)."""
     b, t, w = u.shape
     assert w % bw == 0, (w, bw)
@@ -52,13 +54,12 @@ def rglru_scan_pallas(u: jax.Array, a: jax.Array, h0: jax.Array | None = None,
         pl.BlockSpec((1, bw), lambda i, j: (i, j)),
     ]
 
-    h, h_last = pl.pallas_call(
+    h, h_last = pallas_call(
         functools.partial(_rglru_kernel, t=t),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((b, t, w), jnp.float32),
                    jax.ShapeDtypeStruct((b, w), jnp.float32)],
-        interpret=interpret,
     )(a.astype(jnp.float32), u.astype(jnp.float32), h0.astype(jnp.float32))
     return h, h_last

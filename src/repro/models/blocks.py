@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 from . import act_ctx
 from .config import ModelConfig
@@ -348,8 +347,8 @@ def _apply_moe_shardmap(p, x, cfg: ModelConfig, mesh):
         return out.reshape(x_loc.shape)
 
     args = [p["wi"], p["wg"], p["wo"], p["router"].astype(x.dtype), x]
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(specs_in),
-                   out_specs=x_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(specs_in),
+                       out_specs=x_spec, check_vma=False)
     out = fn(*args)
     if m.dense_residual:
         # dense residual OUTSIDE shard_map: GSPMD shards it once (computing

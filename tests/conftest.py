@@ -1,12 +1,5 @@
 """Shared test configuration.
 
-When the real `hypothesis` package is unavailable (offline images; see
-pyproject's dev extra for the declared dependency), install a deterministic,
-minimal stand-in covering exactly the subset this suite uses: ``@given`` with
-keyword strategies, ``@settings(max_examples=..., deadline=...)``, and
-``st.integers / floats / booleans / sampled_from / lists / just`` with
-``.map()``.  Draws are seeded per test function, so runs are reproducible.
-
 The whole suite runs with the runtime concurrency sanitizer on by default
 (``repro.analysis.sanitizer``: frozen published arrays, shard-set pin
 tracking, lock-order watchdog).  Export ``REPRO_SANITIZE=0`` to measure or
@@ -14,102 +7,6 @@ debug without it; CI's bench jobs do exactly that.
 """
 from __future__ import annotations
 
-import importlib.util
 import os
-import random
-import sys
-import types
-import zlib
 
 os.environ.setdefault("REPRO_SANITIZE", "1")
-
-
-def _install_hypothesis_stub() -> None:
-    class SearchStrategy:
-        def __init__(self, draw_fn):
-            self._draw = draw_fn
-
-        def draw(self, rnd: random.Random):
-            return self._draw(rnd)
-
-        def map(self, fn):
-            return SearchStrategy(lambda rnd: fn(self._draw(rnd)))
-
-    def integers(min_value=0, max_value=2 ** 32):
-        return SearchStrategy(
-            lambda rnd: rnd.randint(int(min_value), int(max_value)))
-
-    def floats(min_value=0.0, max_value=1.0, allow_nan=False,
-               allow_infinity=False, width=64):
-        return SearchStrategy(
-            lambda rnd: rnd.uniform(float(min_value), float(max_value)))
-
-    def booleans():
-        return SearchStrategy(lambda rnd: rnd.random() < 0.5)
-
-    def sampled_from(elements):
-        pool = list(elements)
-        return SearchStrategy(lambda rnd: pool[rnd.randrange(len(pool))])
-
-    def lists(elements, min_size=0, max_size=None):
-        cap = int(max_size) if max_size is not None else int(min_size) + 10
-        return SearchStrategy(
-            lambda rnd: [elements.draw(rnd)
-                         for _ in range(rnd.randint(int(min_size), cap))])
-
-    def just(value):
-        return SearchStrategy(lambda rnd: value)
-
-    def settings(max_examples=100, deadline=None, **_ignored):
-        def deco(fn):
-            fn._stub_settings = {"max_examples": int(max_examples)}
-            return fn
-        return deco
-
-    def given(**strategies):
-        def deco(fn):
-            # NOTE: no functools.wraps -- __wrapped__ would make pytest
-            # introspect fn's signature and demand fixtures for the
-            # strategy-provided parameters.
-            def wrapper(*args, **kwargs):
-                conf = (getattr(wrapper, "_stub_settings", None)
-                        or getattr(fn, "_stub_settings", None)
-                        or {"max_examples": 100})
-                seed = zlib.crc32(fn.__qualname__.encode())
-                rnd = random.Random(seed)
-                for i in range(conf["max_examples"]):
-                    example = {k: s.draw(rnd) for k, s in strategies.items()}
-                    try:
-                        fn(*args, **example, **kwargs)
-                    except Exception as exc:
-                        raise AssertionError(
-                            f"falsifying example (run {i} of {fn.__name__}): "
-                            f"{ {k: _short(v) for k, v in example.items()} }"
-                        ) from exc
-            for attr in ("__name__", "__qualname__", "__doc__", "__module__"):
-                setattr(wrapper, attr, getattr(fn, attr))
-            wrapper._stub_settings = getattr(fn, "_stub_settings", None)
-            return wrapper
-        return deco
-
-    def _short(v, cap=200):
-        r = repr(v)
-        return r if len(r) <= cap else r[:cap] + "..."
-
-    st_mod = types.ModuleType("hypothesis.strategies")
-    for f in (integers, floats, booleans, sampled_from, lists, just):
-        setattr(st_mod, f.__name__, f)
-    st_mod.SearchStrategy = SearchStrategy
-
-    hyp = types.ModuleType("hypothesis")
-    hyp.given = given
-    hyp.settings = settings
-    hyp.strategies = st_mod
-    hyp.__stub__ = True  # marker for debugging
-
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st_mod
-
-
-if importlib.util.find_spec("hypothesis") is None:
-    _install_hypothesis_stub()

@@ -190,6 +190,27 @@ def test_tpu_hardware_profile_uses_roofline_latency():
     assert tpu_lat[8] > TPUCostParams().dma_setup_ns
 
 
+@pytest.mark.parametrize("kind,params,refused", [
+    ("TPU v5 lite", TPUCostParams(), False),
+    ("TPU v4", TPUCostParams(), True),
+    ("TPU v4", TPUCostParams(hbm_gbps=1200.0), False),
+])
+def test_tpu_plan_refuses_another_chip_with_v5e_figures(monkeypatch, kind,
+                                                        params, refused):
+    import repro.index.fit as fit
+    keys = uniform_keys(5_000, seed=10)
+    spec = FitSpec(error=64, candidate_errors=CANDS, hardware="tpu",
+                   tpu_params=params)
+    monkeypatch.setattr(fit, "_attached_tpu_kind", lambda: kind)
+    if refused:
+        with pytest.raises(ValueError, match="TPU v5e figures"):
+            plan(keys, spec)
+    else:
+        assert plan(keys, spec).hardware == "tpu"
+    # the CPU profile never asks which chip is attached
+    assert plan(keys, FitSpec(error=64, candidate_errors=CANDS)).error == 64
+
+
 # ------------------------------------------------------------------ open_index
 def test_open_index_sharded_iff_plan_says_so():
     keys = uniform_keys(20_000, seed=11)

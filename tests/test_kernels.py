@@ -1,4 +1,4 @@
-"""Pallas fitting_lookup kernel vs the pure-jnp oracle (interpret=True on CPU).
+"""Pallas fitting_lookup kernel vs the pure-jnp oracle (interpreted on CPU).
 
 Sweeps shapes / errors / distributions / duplicates / overflow, per the brief.
 """
@@ -28,7 +28,7 @@ def _keys(n, seed=0, dist="uniform"):
 def _check(keys, error, queries, qcap=256):
     idx = build_device_index(keys, error)
     q = jnp.asarray(queries, jnp.float32)
-    got = np.asarray(fitting_lookup(idx, q, qcap=qcap, interpret=True))
+    got = np.asarray(fitting_lookup(idx, q, qcap=qcap))
     want = np.asarray(lookup_ref(idx.keys, q))
     found = want >= 0
     # ranks of found queries must locate an equal key (with duplicates any
@@ -84,7 +84,7 @@ def test_matches_ref_exactly_on_ranks_without_dups():
     idx = build_device_index(keys, 64)
     rng = np.random.default_rng(6)
     q = jnp.asarray(keys[rng.integers(0, 8000, 400)], jnp.float32)
-    got = np.asarray(fitting_lookup(idx, q, interpret=True))
+    got = np.asarray(fitting_lookup(idx, q))
     want = np.asarray(lookup_ref(idx.keys, q))
     np.testing.assert_array_equal(got, want)
 
@@ -98,7 +98,6 @@ def test_property_kernel_equals_oracle(seed, error, n):
     q = np.concatenate([keys[rng.integers(0, n, size=64)],
                         rng.uniform(0, 2 ** 23, size=32)])
     idx = build_device_index(keys, error)
-    got = np.asarray(fitting_lookup(idx, jnp.asarray(q, jnp.float32),
-                                    interpret=True))
+    got = np.asarray(fitting_lookup(idx, jnp.asarray(q, jnp.float32)))
     want = np.asarray(lookup_ref(idx.keys, jnp.asarray(q, jnp.float32)))
     np.testing.assert_array_equal(got, want)

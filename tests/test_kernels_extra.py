@@ -1,5 +1,5 @@
 """flash_attention + rglru_scan Pallas kernels vs pure-jnp oracles
-(interpret=True), sweeping shapes/masks/dtypes per the brief."""
+(interpreted on the CPU backend), sweeping shapes/masks/dtypes per the brief."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +30,7 @@ def _ref(q, k, v, **kw):
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_causal_shapes(tq, s, causal):
     q, k, v = _qkv(2, 4, 2, tq, s, 64)
-    got = flash_attention(q, k, v, causal=causal, interpret=True)
+    got = flash_attention(q, k, v, causal=causal)
     want = _ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -38,8 +38,7 @@ def test_flash_causal_shapes(tq, s, causal):
 
 def test_flash_window_and_softcap():
     q, k, v = _qkv(1, 4, 4, 256, 256, 32, seed=3)
-    got = flash_attention(q, k, v, causal=True, window=64, softcap=50.0,
-                          interpret=True)
+    got = flash_attention(q, k, v, causal=True, window=64, softcap=50.0)
     want = _ref(q, k, v, causal=True, window=64, softcap=50.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -48,7 +47,7 @@ def test_flash_window_and_softcap():
 def test_flash_decode_one_query():
     """Tq=1 against a long KV (the decode shape): end-aligned positions."""
     q, k, v = _qkv(2, 8, 2, 1, 512, 64, seed=5)
-    got = flash_attention(q, k, v, causal=True, interpret=True)
+    got = flash_attention(q, k, v, causal=True)
     want = _ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -56,7 +55,7 @@ def test_flash_decode_one_query():
 
 def test_flash_bf16():
     q, k, v = _qkv(1, 2, 2, 128, 128, 64, seed=7, dtype=jnp.bfloat16)
-    got = flash_attention(q, k, v, causal=True, interpret=True)
+    got = flash_attention(q, k, v, causal=True)
     want = _ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -68,7 +67,7 @@ def test_rglru_kernel_matches_scan(b, t, w):
     rng = np.random.default_rng(b + t)
     u = jnp.asarray(rng.normal(size=(b, t, w)), jnp.float32)
     a = jnp.asarray(rng.uniform(0.3, 0.99, size=(b, t, w)), jnp.float32)
-    got, h_last = rglru_scan_pallas(u, a, interpret=True)
+    got, h_last = rglru_scan_pallas(u, a)
     want = _linear_scan_impl(u, a)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
@@ -81,7 +80,7 @@ def test_rglru_kernel_initial_state():
     u = jnp.asarray(rng.normal(size=(2, 8, 128)), jnp.float32)
     a = jnp.asarray(rng.uniform(0.5, 0.9, size=(2, 8, 128)), jnp.float32)
     h0 = jnp.asarray(rng.normal(size=(2, 128)), jnp.float32)
-    got, _ = rglru_scan_pallas(u, a, h0, interpret=True)
+    got, _ = rglru_scan_pallas(u, a, h0)
     # sequential reference with initial state
     h = np.asarray(h0)
     outs = []

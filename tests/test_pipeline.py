@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 import repro.index as ri
-from repro.index.pipeline import _bucket_size
+from repro.index.engine import _bucket_size
 from repro.serve import (AsyncIndexService, FitSpec, IndexService,
                          PipelineClosed, PipelineOverloaded,
                          ShardedIndexService, open_pipeline)
