@@ -30,7 +30,6 @@ can differ in membership across that boundary.  ``DeviceIndex`` is the f32 devic
 from __future__ import annotations
 
 import functools
-import time
 from typing import Callable, Literal, NamedTuple, Protocol, runtime_checkable
 
 import jax
@@ -42,6 +41,7 @@ from repro.analysis.sanitizer import make_lock
 
 from .query import QueryVerbs
 from .table import SegmentTable, numpy_lookup, numpy_search
+from .telemetry import CH_D2H, CH_H2D, CH_LAUNCH, CH_TIER_PREFIX, NO_SPAN
 
 
 def _bucket_size(n: int) -> int:
@@ -132,9 +132,12 @@ def predict_positions(idx: DeviceIndex, queries: jax.Array) -> jax.Array:
 
 def xla_lookup(idx: DeviceIndex, queries: jax.Array,
                strategy: Literal["window", "bisect"] = "window") -> jax.Array:
-    """Batched point lookup, rank or -1.  jit-safe; ``error`` is static."""
+    """Batched point lookup, rank or -1.  jit-safe; ``error`` is static.
+    Its device steps carry the ``fit.prelude``, ``fit.bisect`` and
+    ``fit.snap`` named scopes."""
     n = idx.keys.shape[0]
-    pred = predict_positions(idx, queries)
+    with jax.named_scope("fit.prelude"):
+        pred = predict_positions(idx, queries)
     e = idx.error
     if strategy == "window":
         w = 2 * e + 2
@@ -144,11 +147,10 @@ def xla_lookup(idx: DeviceIndex, queries: jax.Array,
         lt = (vals < queries[:, None]).sum(axis=1).astype(jnp.int32)
         rank = start + lt
         hit = (vals == queries[:, None]).any(axis=1)
-        rank = snap_leftmost(idx.keys, queries, rank, hit)
-        return jnp.where(hit, rank, -1)
+        with jax.named_scope("fit.snap"):
+            rank = snap_leftmost(idx.keys, queries, rank, hit)
+            return jnp.where(hit, rank, -1)
     # bisect: lo/hi halving on the clipped window
-    lo = jnp.clip(pred - e, 0, n).astype(jnp.int32)
-    hi = jnp.clip(pred + e + 1, 0, n).astype(jnp.int32)
     steps = int(np.ceil(np.log2(2 * e + 2)))
 
     def body(_, lh):
@@ -158,10 +160,14 @@ def xla_lookup(idx: DeviceIndex, queries: jax.Array,
         go = (v < queries) & (lo < hi)
         return jnp.where(go, mid + 1, lo), jnp.where(go, hi, mid)
 
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    ok = (lo < n) & (idx.keys[jnp.minimum(lo, n - 1)] == queries)
-    lo = snap_leftmost(idx.keys, queries, lo, ok)
-    return jnp.where(ok, lo, -1)
+    with jax.named_scope("fit.bisect"):
+        lo = jnp.clip(pred - e, 0, n).astype(jnp.int32)
+        hi = jnp.clip(pred + e + 1, 0, n).astype(jnp.int32)
+        lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
+        ok = (lo < n) & (idx.keys[jnp.minimum(lo, n - 1)] == queries)
+    with jax.named_scope("fit.snap"):
+        lo = snap_leftmost(idx.keys, queries, lo, ok)
+        return jnp.where(ok, lo, -1)
 
 
 def xla_search(idx: DeviceIndex, queries: jax.Array, side: str = "left",
@@ -180,7 +186,8 @@ def xla_search(idx: DeviceIndex, queries: jax.Array, side: str = "left",
     if side not in ("left", "right"):
         raise ValueError(f"side must be 'left' or 'right', got {side!r}")
     n = idx.keys.shape[0]
-    pred = predict_positions(idx, queries)
+    with jax.named_scope("fit.prelude"):
+        pred = predict_positions(idx, queries)
     e = idx.error
     if strategy == "window":
         w = 2 * e + 2
@@ -193,9 +200,8 @@ def xla_search(idx: DeviceIndex, queries: jax.Array, side: str = "left",
         else:
             cmp = vals <= queries[:, None]
         rank = start + (valid & cmp).sum(axis=1).astype(jnp.int32)
-        return snap_side(idx.keys, queries, rank, side)
-    lo = jnp.clip(pred - e, 0, n).astype(jnp.int32)
-    hi = jnp.clip(pred + e + 1, 0, n).astype(jnp.int32)
+        with jax.named_scope("fit.snap"):
+            return snap_side(idx.keys, queries, rank, side)
     steps = int(np.ceil(np.log2(2 * e + 2)))
 
     def body(_, lh):
@@ -206,8 +212,12 @@ def xla_search(idx: DeviceIndex, queries: jax.Array, side: str = "left",
         go = ok & (lo < hi)
         return jnp.where(go, mid + 1, lo), jnp.where(go, hi, mid)
 
-    lo, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    return snap_side(idx.keys, queries, lo, side)
+    with jax.named_scope("fit.bisect"):
+        lo = jnp.clip(pred - e, 0, n).astype(jnp.int32)
+        hi = jnp.clip(pred + e + 1, 0, n).astype(jnp.int32)
+        lo, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
+    with jax.named_scope("fit.snap"):
+        return snap_side(idx.keys, queries, lo, side)
 
 
 # --------------------------------------------------------------------- pallas
@@ -273,40 +283,49 @@ def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
     XLA prelude (router + interpolation + bucketing) -> Pallas compare-reduce
     kernel -> scatter-back + bisect fallback for bucket overflow.  ``idx.error``
     must be a Python int (it sizes the kernel window): jit this with the
-    index arrays as arguments and ``error`` static, as the engines do."""
+    index arrays as arguments and ``error`` static, as the engines do.  The
+    three steps carry the ``fit.prelude``, ``fit.kernel`` and ``fit.snap``
+    named scopes, the overflow fallback ``fit.bisect``."""
     # lazy: repro.kernels imports this module for its thin wrappers
     from repro.kernels.fitting_lookup import fitting_lookup_pallas
 
     plan = make_plan(int(idx.keys.shape[0]), int(idx.error))
-    keys_padded = pad_keys(idx.keys, plan)
     nq = queries.shape[0]
     queries = queries.astype(jnp.float32)
-    q_b, qlo_b, src_b = _pallas_bucketize(idx, queries, plan, qcap)
+    with jax.named_scope("fit.prelude"):
+        keys_padded = pad_keys(idx.keys, plan)
+        q_b, qlo_b, src_b = _pallas_bucketize(idx, queries, plan, qcap)
 
     # --- Pallas kernel over key blocks
-    rank_b, found_b = fitting_lookup_pallas(
-        keys_padded, q_b, qlo_b, kb=plan.kb, window=plan.window)
+    with jax.named_scope("fit.kernel"):
+        rank_b, found_b = fitting_lookup_pallas(
+            keys_padded, q_b, qlo_b, kb=plan.kb, window=plan.window)
 
     # --- scatter back
-    res = jnp.full((nq,), jnp.iinfo(jnp.int32).min, jnp.int32)
-    flat_src = src_b.reshape(-1)
-    flat_ans = jnp.where(found_b.reshape(-1), rank_b.reshape(-1), -1)
-    good = flat_src >= 0
-    res = res.at[jnp.clip(flat_src, 0, None)].max(
-        jnp.where(good, flat_ans, jnp.iinfo(jnp.int32).min))
-    answered = res > jnp.iinfo(jnp.int32).min
-    res = jnp.where(answered, res, -1)
+    with jax.named_scope("fit.snap"):
+        res = jnp.full((nq,), jnp.iinfo(jnp.int32).min, jnp.int32)
+        flat_src = src_b.reshape(-1)
+        flat_ans = jnp.where(found_b.reshape(-1), rank_b.reshape(-1), -1)
+        good = flat_src >= 0
+        res = res.at[jnp.clip(flat_src, 0, None)].max(
+            jnp.where(good, flat_ans, jnp.iinfo(jnp.int32).min))
+        answered = res > jnp.iinfo(jnp.int32).min
+        res = jnp.where(answered, res, -1)
+        if fallback:
+            was_bucketed = jnp.zeros((nq,), bool).at[
+                jnp.clip(flat_src, 0, None)].max(good)
 
     if fallback:
         # bucket-overflow queries (never bucketed) answered by the XLA bisect
         # path; lax.cond skips the work entirely when nothing overflowed.
-        was_bucketed = jnp.zeros((nq,), bool).at[jnp.clip(flat_src, 0, None)].max(good)
         need = ~was_bucketed
-        fb = jax.lax.cond(jnp.any(need),
-                          lambda: xla_lookup(idx, queries, "bisect"),
-                          lambda: res)
+        with jax.named_scope("fit.bisect"):
+            fb = jax.lax.cond(jnp.any(need),
+                              lambda: xla_lookup(idx, queries, "bisect"),
+                              lambda: res)
         res = jnp.where(need, fb, res)
-    return snap_leftmost(idx.keys, queries, res, res >= 0)
+    with jax.named_scope("fit.snap"):
+        return snap_leftmost(idx.keys, queries, res, res >= 0)
 
 
 def pallas_search(idx: DeviceIndex, queries: jax.Array, side: str = "left", *,
@@ -325,26 +344,32 @@ def pallas_search(idx: DeviceIndex, queries: jax.Array, side: str = "left", *,
     from repro.kernels.fitting_lookup import fitting_lookup_pallas
 
     plan = make_plan(int(idx.keys.shape[0]), int(idx.error))
-    keys_padded = pad_keys(idx.keys, plan)
     nq = queries.shape[0]
     queries = queries.astype(jnp.float32)
-    q_b, qlo_b, src_b = _pallas_bucketize(idx, queries, plan, qcap)
+    with jax.named_scope("fit.prelude"):
+        keys_padded = pad_keys(idx.keys, plan)
+        q_b, qlo_b, src_b = _pallas_bucketize(idx, queries, plan, qcap)
 
-    rank_b, _ = fitting_lookup_pallas(
-        keys_padded, q_b, qlo_b, kb=plan.kb, window=plan.window, side=side)
+    with jax.named_scope("fit.kernel"):
+        rank_b, _ = fitting_lookup_pallas(
+            keys_padded, q_b, qlo_b, kb=plan.kb, window=plan.window,
+            side=side)
 
-    res = jnp.full((nq,), jnp.iinfo(jnp.int32).min, jnp.int32)
-    flat_src = src_b.reshape(-1)
-    flat_ans = rank_b.reshape(-1)
-    good = flat_src >= 0
-    res = res.at[jnp.clip(flat_src, 0, None)].max(
-        jnp.where(good, flat_ans, jnp.iinfo(jnp.int32).min))
+    with jax.named_scope("fit.snap"):
+        res = jnp.full((nq,), jnp.iinfo(jnp.int32).min, jnp.int32)
+        flat_src = src_b.reshape(-1)
+        flat_ans = rank_b.reshape(-1)
+        good = flat_src >= 0
+        res = res.at[jnp.clip(flat_src, 0, None)].max(
+            jnp.where(good, flat_ans, jnp.iinfo(jnp.int32).min))
     need = res == jnp.iinfo(jnp.int32).min       # bucket-overflow queries
-    fb = jax.lax.cond(jnp.any(need),
-                      lambda: xla_search(idx, queries, side, "bisect"),
-                      lambda: res)
+    with jax.named_scope("fit.bisect"):
+        fb = jax.lax.cond(jnp.any(need),
+                          lambda: xla_search(idx, queries, side, "bisect"),
+                          lambda: res)
     res = jnp.where(need, fb, res)
-    return snap_side(idx.keys, queries, res, side)
+    with jax.named_scope("fit.snap"):
+        return snap_side(idx.keys, queries, res, side)
 
 
 # ------------------------------------------------------------------- registry
@@ -438,28 +463,40 @@ class _DeviceEngine(QueryVerbs):
     padding lanes repeat the batch, so they spread over the key blocks like
     the real queries) and the tail is sliced off: a sharded service splits
     every batch by routing, and without the bucket each distinct per-shard
-    size would be a fresh compile."""
+    size would be a fresh compile.
+
+    ``monitor`` (a ``repro.index.telemetry.Monitor``) splits each call into
+    three spans: ``engine.h2d`` (bucket padding and the host-to-device
+    copy), ``engine.launch`` (the jitted call until it returns) and
+    ``engine.d2h`` (the blocking read of the answer)."""
 
     _lookup_impl: Callable
     _search_impl: Callable
 
     def __init__(self, table: SegmentTable, lookup_opts: dict | None = None,
-                 search_opts: dict | None = None):
+                 search_opts: dict | None = None, monitor=None):
         self.table = table
         self.index = device_index(table)
+        self.monitor = monitor
         self._arrays = tuple(self.index)[:5]
         self._lookup_opts = tuple(sorted((lookup_opts or {}).items()))
         self._search_opts = dict(search_opts or {})
 
     def _run(self, impl, queries, opts) -> np.ndarray:
-        q = np.asarray(queries, np.float32)
-        flat = q.ravel()
-        n = flat.size
-        if n:
-            flat = np.resize(flat, _bucket_size(n))
-        out = _run_on_index(self._arrays, jnp.asarray(flat), impl=impl,
-                            error=self.index.error, opts=opts)
-        return np.asarray(out)[:n].reshape(q.shape)
+        mon = self.monitor
+        with (NO_SPAN if mon is None else mon.span(CH_H2D)):
+            q = np.asarray(queries, np.float32)
+            flat = q.ravel()
+            n = flat.size
+            if n:
+                flat = np.resize(flat, _bucket_size(n))
+            dq = jnp.asarray(flat)
+        with (NO_SPAN if mon is None else mon.span(CH_LAUNCH)):
+            out = _run_on_index(self._arrays, dq, impl=impl,
+                                error=self.index.error, opts=opts)
+        with (NO_SPAN if mon is None else mon.span(CH_D2H)):
+            out = np.asarray(out)
+        return out[:n].reshape(q.shape)
 
     def lookup(self, queries) -> np.ndarray:
         if self.table.n_keys == 0:   # gathers on a 0-length device array are
@@ -494,8 +531,9 @@ class XlaWindowEngine(_DeviceEngine):
     _lookup_impl = xla_lookup
     _search_impl = xla_search
 
-    def __init__(self, table: SegmentTable):
-        super().__init__(table, {"strategy": "window"}, {"strategy": "window"})
+    def __init__(self, table: SegmentTable, *, monitor=None):
+        super().__init__(table, {"strategy": "window"}, {"strategy": "window"},
+                         monitor)
 
 
 @register_backend("xla-bisect")
@@ -503,8 +541,9 @@ class XlaBisectEngine(_DeviceEngine):
     _lookup_impl = xla_lookup
     _search_impl = xla_search
 
-    def __init__(self, table: SegmentTable):
-        super().__init__(table, {"strategy": "bisect"}, {"strategy": "bisect"})
+    def __init__(self, table: SegmentTable, *, monitor=None):
+        super().__init__(table, {"strategy": "bisect"}, {"strategy": "bisect"},
+                         monitor)
 
 
 @register_backend("pallas")
@@ -513,9 +552,9 @@ class PallasEngine(_DeviceEngine):
     _search_impl = pallas_search
 
     def __init__(self, table: SegmentTable, *, qcap: int = 256,
-                 fallback: bool = True):
+                 fallback: bool = True, monitor=None):
         super().__init__(table, {"qcap": qcap, "fallback": fallback},
-                         {"qcap": qcap})
+                         {"qcap": qcap}, monitor)
 
 
 @register_backend("dispatch")
@@ -546,10 +585,12 @@ class DispatchEngine(QueryVerbs):
     pin them (e.g. from a measured sweep or an ``IndexPlan``).
 
     ``monitor`` (a ``repro.index.telemetry.Monitor``) turns on per-tier
-    telemetry: every routed ``lookup``/``search`` records ``(batch_size,
-    wall_ns)`` on the ``tier.<small|medium|large>`` channel, which is exactly
-    the sample shape ``repro.core.cost_model.fit_tier_curves`` re-fits the
-    tier cost curves from.  ``None`` (the default) keeps the hot path
+    telemetry: every routed ``lookup``/``search`` is a span on the
+    ``tier.<small|medium|large>`` channel whose rows lead with ``(batch_size,
+    wall_ns)``, exactly the sample shape ``repro.core.cost_model.
+    fit_tier_curves`` re-fits the tier cost curves from.  The monitor is
+    also handed to the device tier engines, whose ``engine.*`` spans nest
+    under the tier's.  ``None`` (the default) keeps the hot path
     record-free.
     """
 
@@ -600,8 +641,11 @@ class DispatchEngine(QueryVerbs):
             with self._lock:           # don't jit the same tier twice
                 eng = self._engines.get(name)
                 if eng is None:
-                    eng = make_engine(self.table, name,
-                                      **self._engine_opts.get(name, {}))
+                    opts = dict(self._engine_opts.get(name, {}))
+                    if self.monitor is not None \
+                            and issubclass(_BACKENDS[name], _DeviceEngine):
+                        opts["monitor"] = self.monitor
+                    eng = make_engine(self.table, name, **opts)
                     self._engines[name] = eng
         return eng
 
@@ -612,11 +656,8 @@ class DispatchEngine(QueryVerbs):
         mon = self.monitor
         if mon is None:
             return eng.lookup(queries)
-        t0 = time.perf_counter_ns()
-        out = eng.lookup(queries)
-        # channel name matches repro.index.telemetry.CH_TIER_PREFIX
-        mon.record("tier." + self.tier_for(n), n, time.perf_counter_ns() - t0)
-        return out
+        with mon.span(CH_TIER_PREFIX + self.tier_for(n), n, wall=True):
+            return eng.lookup(queries)
 
     @hot_path
     def search(self, queries, side: str = "left") -> np.ndarray:
@@ -628,10 +669,8 @@ class DispatchEngine(QueryVerbs):
         mon = self.monitor
         if mon is None:
             return eng.search(queries, side)
-        t0 = time.perf_counter_ns()
-        out = eng.search(queries, side)
-        mon.record("tier." + self.tier_for(n), n, time.perf_counter_ns() - t0)
-        return out
+        with mon.span(CH_TIER_PREFIX + self.tier_for(n), n, wall=True):
+            return eng.search(queries, side)
 
     def prewarm(self, batch_sizes=None) -> None:
         """Opt-in eager tier construction + compilation.

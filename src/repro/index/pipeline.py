@@ -74,9 +74,9 @@ import numpy as np
 
 from repro.analysis.sanitizer import make_lock
 
-from .telemetry import (CH_FLUSH, CH_QUEUE_DEPTH, CH_SOJOURN, FLUSH_DEADLINE,
-                        FLUSH_DRAIN, FLUSH_INLINE, FLUSH_THRESHOLD, Monitor,
-                        PipelineMetrics, Replanner, ServiceMetrics)
+from .telemetry import (CH_FLUSH, CH_IDLE, CH_WAIT, FLUSH_DEADLINE,
+                        FLUSH_DRAIN, FLUSH_INLINE, FLUSH_THRESHOLD, NO_SPAN,
+                        Monitor, PipelineMetrics, Replanner, ServiceMetrics)
 
 if TYPE_CHECKING:   # the service types are duck-typed at runtime
     from .fit import FitSpec, IndexPlan
@@ -97,8 +97,8 @@ class PipelineOverloaded(RuntimeError):
 
 class _Request:
     """One caller's queued submission: queries + the future to resolve.
-    ``t_enq`` stamps the enqueue time so the flusher can report per-request
-    sojourn (queue wait + fused service call) to the monitor."""
+    ``t_enq`` stamps the enqueue time (``perf_counter_ns``) so the flusher
+    can record the request's queue wait as a ``pipeline.wait`` span."""
     __slots__ = ("queries", "shape", "future", "t_enq")
 
     def __init__(self, queries: np.ndarray, shape: tuple[int, ...],
@@ -149,8 +149,8 @@ class AsyncIndexService:
                  monitor: Monitor | None = None,
                  replanner: Replanner | None = None):
         plan = getattr(service, "plan", None)
-        # telemetry defaults to the service's monitor so the pipeline channels
-        # (queue depth / flush cause / sojourn) land next to the tier samples
+        # telemetry defaults to the service's monitor so the pipeline spans
+        # (flush, queue wait) land next to the tier spans they contain
         self.monitor = monitor if monitor is not None \
             else getattr(service, "monitor", None)
         self.replanner = replanner
@@ -269,12 +269,13 @@ class AsyncIndexService:
             self._check_open()
             with self._lock:
                 self._stats["inline_batches"] += 1
-            if self.monitor is not None:
-                self.monitor.record(CH_FLUSH, FLUSH_INLINE, int(q.size))
-            try:
-                fut.set_result(self._run(kind, q).reshape(shape))
-            except BaseException as exc:  # surfaced via the future
-                fut.set_exception(exc)
+            mon = self.monitor
+            with (NO_SPAN if mon is None
+                  else mon.span(CH_FLUSH, FLUSH_INLINE, int(q.size))):
+                try:
+                    fut.set_result(self._run(kind, q).reshape(shape))
+                except BaseException as exc:  # surfaced via the future
+                    fut.set_exception(exc)
             return fut
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._lock:
@@ -327,66 +328,74 @@ class AsyncIndexService:
         """Fuse each verb bucket into one service call; scatter per-caller
         slices back through the futures.  An exception fails exactly the
         futures of the batch that raised it.  ``cause`` is the flush-trigger
-        code (:data:`FLUSH_THRESHOLD`/`FLUSH_DEADLINE`/`FLUSH_DRAIN`)
-        recorded per fused bucket on the monitor, alongside each resolved
-        request's sojourn (enqueue -> result) -- both off the caller path."""
+        code (:data:`FLUSH_THRESHOLD`/`FLUSH_DEADLINE`/`FLUSH_DRAIN`).  With
+        a monitor each fused bucket is a ``pipeline.flush`` span, and each
+        of its requests gets a ``pipeline.wait`` span from its enqueue to
+        the flush's start, whose parent is that flush."""
         mon = self.monitor
         for kind, reqs in batches:
-            fused = (reqs[0].queries if len(reqs) == 1
-                     else np.concatenate([r.queries for r in reqs]))
-            with self._lock:
-                self._stats["flushes"] += 1
-                self._stats["coalesced_queries"] += int(fused.size)
-                self._stats["max_fused_batch"] = max(
-                    self._stats["max_fused_batch"], int(fused.size))
-            if mon is not None:
-                mon.record(CH_FLUSH, cause, int(fused.size))
-            try:
-                out = self._run(kind, fused)
-            except BaseException as exc:
+            size = sum(r.queries.size for r in reqs)
+            span = None if mon is None else mon.span(CH_FLUSH, cause, size)
+            with (NO_SPAN if span is None else span):
+                if span is not None:
+                    for r in reqs:
+                        mon.record_span(CH_WAIT, r.t_enq,
+                                        span.start_ns - r.t_enq, span.span_id)
+                with self._lock:
+                    self._stats["flushes"] += 1
+                    self._stats["coalesced_queries"] += size
+                    self._stats["max_fused_batch"] = max(
+                        self._stats["max_fused_batch"], size)
+                fused = (reqs[0].queries if len(reqs) == 1
+                         else np.concatenate([r.queries for r in reqs]))
+                try:
+                    out = self._run(kind, fused)
+                except BaseException as exc:
+                    for r in reqs:
+                        r.future.set_exception(exc)
+                    continue
+                off = 0
                 for r in reqs:
-                    r.future.set_exception(exc)
-                continue
-            off = 0
-            for r in reqs:
-                n = r.queries.size
-                r.future.set_result(out[off:off + n].reshape(r.shape))
-                off += n
-            if mon is not None:
-                now = time.perf_counter_ns()
-                for r in reqs:
-                    mon.record(CH_SOJOURN, now - r.t_enq)
+                    n = r.queries.size
+                    r.future.set_result(out[off:off + n].reshape(r.shape))
+                    off += n
 
     def _flush_loop(self) -> None:
+        mon = self.monitor
         try:
             while True:
-                with self._lock:
-                    cause = FLUSH_DRAIN
-                    while True:
-                        if self._closed:
-                            break
-                        now = time.monotonic()
-                        if self._queued >= self.flush_threshold:
-                            self._stats["threshold_flushes"] += 1
-                            cause = FLUSH_THRESHOLD
-                            break
-                        if self._oldest is not None:
-                            expires = self._oldest + self.max_wait_us * 1e-6
-                            if now >= expires:
-                                self._stats["deadline_flushes"] += 1
-                                cause = FLUSH_DEADLINE
-                                break
-                            self._work.wait(expires - now)
-                        else:
-                            self._work.wait()
-                    if self._closed:
-                        return          # close() drains under its own lock
-                    if self.monitor is not None:
-                        self.monitor.record(CH_QUEUE_DEPTH, self._queued)
-                    batches = self._take_batches()
-                self._flush(batches, cause)
+                # with a monitor, the time between flushes is a
+                # ``pipeline.idle`` host event on the profiler's trace
+                with (NO_SPAN if mon is None else mon.annotate(CH_IDLE)):
+                    taken = self._await_batches()
+                if taken is None:
+                    return              # close() drains under its own lock
+                self._flush(*taken)
         except BaseException as exc:     # pragma: no cover - defensive
             self._record_fatal(exc)
+
+    def _await_batches(self) -> tuple[list, int] | None:
+        """Block until a flush is due; claim the queue and return it with
+        the flush's cause, or None once the pipeline is closed."""
+        with self._lock:
+            while True:
+                if self._closed:
+                    return None
+                now = time.monotonic()
+                if self._queued >= self.flush_threshold:
+                    self._stats["threshold_flushes"] += 1
+                    cause = FLUSH_THRESHOLD
+                    break
+                if self._oldest is not None:
+                    expires = self._oldest + self.max_wait_us * 1e-6
+                    if now >= expires:
+                        self._stats["deadline_flushes"] += 1
+                        cause = FLUSH_DEADLINE
+                        break
+                    self._work.wait(expires - now)
+                else:
+                    self._work.wait()
+            return self._take_batches(), cause
 
     # ------------------------------------------------------------- maintenance
     def _maintenance_loop(self) -> None:

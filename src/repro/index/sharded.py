@@ -66,9 +66,9 @@ from repro.index.table import (SegmentTable, route_keys, shard_boundaries,
 
 from .query import PointResult, RangeResult, check_range, check_side
 from .snapshot import ServingHandle, Snapshot, SnapshotPublisher
-from .telemetry import (CH_PUBLISH, CH_QUERY_MIX, CH_REBALANCE,
-                        CH_SERVED_KEYS, CH_SHARD_LOAD, CH_SKEW, Monitor,
-                        ServiceMetrics, ShardMetrics, tier_metrics)
+from .telemetry import (CH_MERGE, CH_PUBLISH, CH_REBALANCE, CH_ROUTE,
+                        CH_SERVED_KEYS, NO_SPAN, Monitor, ServiceMetrics,
+                        ShardMetrics, tier_metrics)
 
 if TYPE_CHECKING:  # runtime import is lazy (fit builds services via plans)
     from .fit import IndexPlan
@@ -534,26 +534,9 @@ class ShardedIndexService:
                 except ValueError:   # < n_shards distinct keys: no safe recut
                     self._rebalance_skipped += 1
             if published and self.monitor is not None:
-                self._record_publish(len(published),
-                                     time.perf_counter_ns() - t0)
+                self.monitor.record(CH_PUBLISH, len(published),
+                                    time.perf_counter_ns() - t0)
             return published
-
-    def _record_publish(self, n_published: int, wall_ns: int) -> None:
-        """Publish-cadence telemetry: duration, skew, per-shard load, and the
-        cumulative query-shape mix (the Replanner's range-fraction input)."""
-        mon = self.monitor
-        mon.record(CH_PUBLISH, n_published, wall_ns)
-        mon.record(CH_SKEW, self.imbalance())
-        for d, load in enumerate(self.shard_loads()):
-            mon.record(CH_SHARD_LOAD, d, float(load))
-        # copy under the lock, record after releasing it: Monitor.record
-        # takes Monitor._make_lock, which ranks *above* _counts_lock in
-        # contracts.LOCK_ORDER -- recording while holding the counter lock
-        # is exactly the inversion the runtime watchdog exists to catch
-        with self._counts_lock:
-            c = dict(self._query_counts)
-        mon.record(CH_QUERY_MIX, c["points"], c["ranges"], c["counts"],
-                   c["predecessors"], c["successors"], c["searches"])
 
     # ------------------------------------------------------------- rebalance
     def shard_loads(self) -> np.ndarray:
@@ -788,16 +771,31 @@ class ShardedIndexService:
                 return ss.handles[0].lookup(queries, backend)
             engines = [h.engine(backend) for h in ss.handles]
             q = np.asarray(queries, np.float64)
-            sid = route_keys(ss.boundaries, q)
             sizes = [e.table.n_keys for e in engines]
             offsets = np.concatenate([[0],
                                       np.cumsum(sizes)[:-1]]).astype(np.int64)
-            out = np.full(q.shape, -1, np.int64)
+            parts = self._route(ss, q)
+            local = [np.asarray(engines[d].lookup(part), np.int64)
+                     for d, _, part in parts]
+            mon = self.monitor
+            with (NO_SPAN if mon is None else mon.span(CH_MERGE)):
+                out = np.full(q.shape, -1, np.int64)
+                for (d, mask, _), ranks in zip(parts, local):
+                    out[mask] = np.where(ranks >= 0, ranks + offsets[d], -1)
+            return out
+
+    def _route(self, ss: ShardSet, q: np.ndarray
+               ) -> list[tuple[int, np.ndarray, np.ndarray]]:
+        """``(shard, mask, queries)`` for each shard some query routes to,
+        in shard order: a ``shard.route`` span when a monitor is attached."""
+        mon = self.monitor
+        with (NO_SPAN if mon is None else mon.span(CH_ROUTE)):
+            sid = route_keys(ss.boundaries, q)
+            parts = []
             for d in np.unique(sid):
                 mask = sid == d
-                local = np.asarray(engines[d].lookup(q[mask]), np.int64)
-                out[mask] = np.where(local >= 0, local + offsets[d], -1)
-            return out
+                parts.append((int(d), mask, q[mask]))
+            return parts
 
     # ------------------------------------------------------ typed query plane
     def _pin_view(self, backend: str | None):
@@ -823,12 +821,14 @@ class ShardedIndexService:
         shard, so local searchsorted + offset == global searchsorted."""
         ss, _, engines, offsets, _ = view
         q = np.asarray(queries, np.float64)
-        sid = route_keys(ss.boundaries, q)
-        out = np.empty(q.shape, np.int64)
-        for d in np.unique(sid):
-            mask = sid == d
-            out[mask] = np.asarray(engines[d].search(q[mask], side),
-                                   np.int64) + offsets[d]
+        parts = self._route(ss, q)
+        local = [np.asarray(engines[d].search(part, side), np.int64)
+                 for d, _, part in parts]
+        mon = self.monitor
+        with (NO_SPAN if mon is None else mon.span(CH_MERGE)):
+            out = np.empty(q.shape, np.int64)
+            for (d, mask, _), ranks in zip(parts, local):
+                out[mask] = ranks + offsets[d]
         return out
 
     def search(self, queries, side: str = "left",

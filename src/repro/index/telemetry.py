@@ -11,12 +11,24 @@ planner (``repro.index.fit``) leaves open:
   exactly the semantics a fixed-capacity telemetry ring wants).  Recording
   hooks are threaded through the serving stack:
 
-      DispatchEngine        tier.<small|medium|large>: (batch_size, wall_ns)
-      AsyncIndexService     pipeline.queue_depth / pipeline.flush (cause,
-                            fused batch size) / pipeline.sojourn (ns)
-      ShardedIndexService   service.publish / service.rebalance (wall ns),
-                            service.shard_load, service.skew,
-                            service.query_mix, served.keys (query samples)
+      AsyncIndexService     pipeline.flush span (cause, fused batch size);
+                            pipeline.wait span per request (enqueue -> its
+                            flush begins; parent = that flush);
+                            pipeline.idle (profiler host event only)
+      ShardedIndexService   shard.route / shard.merge spans;
+                            service.publish / service.rebalance (wall ns),
+                            served.keys (query samples)
+      DispatchEngine        tier.<small|medium|large> span (batch_size,
+                            wall_ns)
+      device tier engines   engine.h2d / engine.launch / engine.d2h spans
+
+  A span (:meth:`Monitor.span`) is one timed step: its row is the caller's
+  attribute columns followed by ``SPAN_COLUMNS`` -- start and duration on
+  the ``time.perf_counter_ns`` clock, its id, and the id of the span open on
+  the same thread when it began (0 for none).  While open it is also a
+  ``jax.profiler.TraceAnnotation`` of the channel's name, so a profiler
+  trace shows the program's steps on the device planes' clock.  With no
+  monitor attached a call site enters :data:`NO_SPAN` and builds nothing.
 
   Backends are pluggable: :class:`MemoryBackend` (default, rings only) and
   :class:`JSONLBackend` (same rings; ``flush()`` appends rows recorded since
@@ -48,9 +60,11 @@ The typed observability surface lives here too: :class:`ServiceMetrics`
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import json
+import threading
 import time
 
 import numpy as np
@@ -68,13 +82,15 @@ CH_TIER_PREFIX = "tier."            # + small|medium|large: (batch, wall_ns)
 CH_SERVED_KEYS = "served.keys"      # vector rows: sampled query keys
 CH_PUBLISH = "service.publish"      # (shards_published, wall_ns)
 CH_REBALANCE = "service.rebalance"  # (moved_keys, wall_ns)
-CH_SHARD_LOAD = "service.shard_load"  # (shard, load)
-CH_SKEW = "service.skew"            # (imbalance,)
-CH_QUERY_MIX = "service.query_mix"  # (points, ranges, counts, preds, succs,
-                                    #  searches) cumulative at publish time
-CH_QUEUE_DEPTH = "pipeline.queue_depth"  # (queued_queries,)
-CH_FLUSH = "pipeline.flush"         # (cause, fused_batch)
-CH_SOJOURN = "pipeline.sojourn"     # (ns,) per-request enqueue->resolve
+CH_QUERY_MIX = "service.query_mix"  # (verb_index,) per LSM verb call
+CH_FLUSH = "pipeline.flush"         # span (cause, fused_batch)
+CH_WAIT = "pipeline.wait"           # span per request: enqueue -> its flush
+CH_IDLE = "pipeline.idle"           # host event: the flusher awaits work
+CH_ROUTE = "shard.route"            # span: route keys, split per shard
+CH_MERGE = "shard.merge"            # span: lift local ranks, write answers
+CH_H2D = "engine.h2d"               # span: bucket pad + host-to-device
+CH_LAUNCH = "engine.launch"         # span: the jitted call until it returns
+CH_D2H = "engine.d2h"               # span: blocking read of the answer
 CH_REPLAN = "replan"                # (applied, win, small_max, large_min,
                                     #  n_shards)
 CH_MEMTABLE = "lsm.memtable"        # (keys, tombstones, capacity) occupancy
@@ -91,6 +107,13 @@ XCHG_ALLGATHER, XCHG_A2A = 0, 1
 
 # pipeline.flush cause codes
 FLUSH_THRESHOLD, FLUSH_DEADLINE, FLUSH_DRAIN, FLUSH_INLINE = 0, 1, 2, 3
+
+# the trailing columns of every span row
+SPAN_COLUMNS = ("start_ns", "dur_ns", "span_id", "parent_id")
+
+# the context a call site enters instead of a span when no monitor is
+# attached: one shared instance, so the off path builds nothing
+NO_SPAN = contextlib.nullcontext()
 
 METRICS_SCHEMA_VERSION = 1
 
@@ -219,6 +242,9 @@ class Monitor:
     ``enabled`` is False, so a monitor can be installed permanently and
     toggled.
 
+    ``span(name, *attrs)`` times one step into ``name`` as a
+    :class:`Span` row and a profiler host event of the same name.
+
     Readers (``channel()``/``channels()``/``count()``) snapshot the rings;
     they are meant for the maintenance thread / dashboards, not the hot
     path.  ``backend`` picks the store: the default :class:`MemoryBackend`
@@ -237,6 +263,8 @@ class Monitor:
         self.enabled = True
         self._channels: dict[str, _Ring] = {}
         self._make_lock = make_lock("Monitor._make_lock")
+        self._span_ids = itertools.count(1)   # 0 means "no parent"
+        self._open = threading.local()         # .span: innermost open span
 
     # ------------------------------------------------------------- hot path
     @hot_path
@@ -258,6 +286,30 @@ class Monitor:
         if ring is None:
             ring = self._make(name, "vector")
         ring.append(np.array(values, np.float64).ravel())
+
+    def span(self, channel: str, *attrs, wall: bool = False) -> "Span":
+        """A context manager timing one step into ``channel``: on exit it
+        appends ``attrs`` then :data:`SPAN_COLUMNS` (see :class:`Span`).
+        ``wall=True`` also writes the duration as the last attribute column,
+        for channels whose readers take ``(x, wall_ns)`` rows (``tier.*``)."""
+        return Span(self, channel, attrs, wall)
+
+    @hot_path
+    def record_span(self, channel: str, start_ns: int, dur_ns: int,
+                    parent_id: int) -> int:
+        """Append a span row for a step timed outside a ``with`` block (e.g.
+        a request's queue wait, which begins on another thread) under
+        ``parent_id``.  Returns the new span's id."""
+        span_id = next(self._span_ids)
+        self.record(channel, start_ns, dur_ns, span_id, parent_id)
+        return span_id
+
+    def annotate(self, name: str):
+        """A profiler host event with no ring row: for a step worth seeing
+        on the trace's clock but not worth a row (the flusher's idle
+        wait)."""
+        from jax.profiler import TraceAnnotation
+        return TraceAnnotation(name)
 
     def _make(self, name: str, kind: str) -> _Ring:
         with self._make_lock:
@@ -286,12 +338,14 @@ class Monitor:
 
     def tier_samples(self) -> dict[str, np.ndarray]:
         """The ``tier.*`` channels keyed by bare tier name -- the exact input
-        shape :func:`repro.core.cost_model.fit_tier_curves` consumes."""
+        shape :func:`repro.core.cost_model.fit_tier_curves` consumes: the
+        leading ``(batch_size, wall_ns)`` of each row, whatever follows."""
         out = {}
         for tier in _TIERS:
-            rows = self.channel(CH_TIER_PREFIX + tier)
-            if rows.size:
-                out[tier] = rows
+            ring = self._channels.get(CH_TIER_PREFIX + tier)
+            rows = [] if ring is None else ring.snapshot()
+            if rows:
+                out[tier] = np.asarray([r[:2] for r in rows], np.float64)
         return out
 
     # ------------------------------------------------------------ lifecycle
@@ -310,6 +364,48 @@ class Monitor:
                 self._channels = {}
             else:
                 self._channels.pop(name, None)
+
+
+class Span:
+    """One timed step of the served path (built by :meth:`Monitor.span`).
+
+    ``__enter__`` takes a fresh id, notes the span already open on this
+    thread as its parent, opens a ``jax.profiler.TraceAnnotation`` named
+    after the channel and reads the clock; ``__exit__`` appends one row:
+    ``attrs`` (+ the duration when ``wall``), then ``start_ns``, ``dur_ns``,
+    ``span_id``, ``parent_id``.  ``jax`` is imported on the first span, so a
+    process with no monitor never imports it from here."""
+
+    __slots__ = ("monitor", "channel", "attrs", "wall", "span_id",
+                 "parent_id", "start_ns", "_parent", "_annotation")
+
+    def __init__(self, monitor: Monitor, channel: str, attrs: tuple,
+                 wall: bool):
+        self.monitor = monitor
+        self.channel = channel
+        self.attrs = attrs
+        self.wall = wall
+
+    def __enter__(self) -> "Span":
+        from jax.profiler import TraceAnnotation
+        mon = self.monitor
+        self._parent = getattr(mon._open, "span", None)
+        self.parent_id = 0 if self._parent is None else self._parent.span_id
+        self.span_id = next(mon._span_ids)
+        mon._open.span = self
+        self._annotation = TraceAnnotation(self.channel)
+        self._annotation.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dur = time.perf_counter_ns() - self.start_ns
+        self._annotation.__exit__(*exc)
+        mon = self.monitor
+        mon._open.span = self._parent
+        wall = (dur,) if self.wall else ()
+        mon.record(self.channel, *self.attrs, *wall, self.start_ns, dur,
+                   self.span_id, self.parent_id)
 
 
 # ==================================================================== metrics
