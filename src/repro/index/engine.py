@@ -249,31 +249,53 @@ def pad_keys(keys: jax.Array, plan: LookupPlan) -> jax.Array:
     return jnp.pad(keys.astype(jnp.float32), (0, pad), constant_values=jnp.inf)
 
 
+class BucketSlots(NamedTuple):
+    """Where :func:`_pallas_bucketize` put each query.  The ``i``-th query in
+    block order is ``queries[order[i]]``; it sits in bucket ``(blk[i],
+    slot[i])`` when ``ok[i]``, and otherwise overflowed its bucket (its
+    ``slot`` is then ``qcap``, one past the row)."""
+    order: jax.Array  # (nq,) i32  query index, in block order
+    blk: jax.Array    # (nq,) i32  key block the query's window starts in
+    slot: jax.Array   # (nq,) i32  column in that block's bucket row
+    ok: jax.Array     # (nq,) bool the query was bucketed
+
+
+# the read-back's mark for a query its bucket could not hold (ranks >= -1)
+_UNSET = np.iinfo(np.int32).min
+
+
 def _pallas_bucketize(idx: DeviceIndex, queries: jax.Array, plan: LookupPlan,
-                      qcap: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+                      qcap: int) -> tuple[jax.Array, jax.Array, BucketSlots]:
     """The XLA prelude shared by :func:`pallas_lookup` and
     :func:`pallas_search`: router + interpolation -> window starts -> queries
     bucketed by the key block their window starts in.  Returns ``(q_b,
-    qlo_b, src_b)``: per-block query values (+inf filler), global window
-    starts, and source indices (-1 filler; a query missing from ``src_b``
-    overflowed its bucket and must be answered by the caller's fallback)."""
+    qlo_b, slots)``: per-block query values (+inf filler), global window
+    starts, and each query's :class:`BucketSlots` coordinates, which the
+    caller reads its answers back at.  A query not ``ok`` overflowed its
+    bucket and must be answered by the caller's fallback."""
     nq = queries.shape[0]
     pred = predict_positions(idx, queries)
     qlo = jnp.clip(pred - idx.error, 0, plan.n_pad - plan.window).astype(jnp.int32)
     blk = qlo // plan.kb                                    # owning key block
-    order = jnp.argsort(blk, stable=True)
+    order = jnp.argsort(blk, stable=True).astype(jnp.int32)
     blk_s = blk[order]
     slot = jnp.arange(nq, dtype=jnp.int32) - jnp.searchsorted(
         blk_s, blk_s, side="left").astype(jnp.int32)        # rank within bucket
     ok = slot < qcap
+    slot = jnp.where(ok, slot, qcap)     # overflow: off the row, so dropped
     q_b = jnp.full((plan.n_blocks, qcap), jnp.inf, jnp.float32)
     qlo_b = jnp.zeros((plan.n_blocks, qcap), jnp.int32)
-    src_b = jnp.full((plan.n_blocks, qcap), -1, jnp.int32)
-    slot_c = jnp.where(ok, slot, qcap - 1)
-    q_b = q_b.at[blk_s, slot_c].set(jnp.where(ok, queries[order], jnp.inf))
-    qlo_b = qlo_b.at[blk_s, slot_c].set(jnp.where(ok, qlo[order], 0))
-    src_b = src_b.at[blk_s, slot_c].set(jnp.where(ok, order.astype(jnp.int32), -1))
-    return q_b, qlo_b, src_b
+    q_b = q_b.at[blk_s, slot].set(queries[order], mode="drop")
+    qlo_b = qlo_b.at[blk_s, slot].set(qlo[order], mode="drop")
+    return q_b, qlo_b, BucketSlots(order, blk_s, slot, ok)
+
+
+def _read_back(slots: BucketSlots, ans: jax.Array) -> jax.Array:
+    """``ans`` (one answer per query in block order, gathered at its
+    clipped slot) back in query order, ``_UNSET`` where the query
+    overflowed: one unique-index scatter of nq."""
+    ans = jnp.where(slots.ok, ans, _UNSET)
+    return jnp.zeros_like(ans).at[slots.order].set(ans, unique_indices=True)
 
 
 def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
@@ -281,44 +303,38 @@ def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
     """Batched point lookup via the Pallas kernel.  Returns ranks (-1 absent).
 
     XLA prelude (router + interpolation + bucketing) -> Pallas compare-reduce
-    kernel -> scatter-back + bisect fallback for bucket overflow.  ``idx.error``
-    must be a Python int (it sizes the kernel window): jit this with the
-    index arrays as arguments and ``error`` static, as the engines do.  The
-    three steps carry the ``fit.prelude``, ``fit.kernel`` and ``fit.snap``
-    named scopes, the overflow fallback ``fit.bisect``."""
+    kernel -> each query's rank and hit read at its own bucket slot (nq
+    gathers, put back in query order) + bisect fallback for bucket overflow.
+    ``idx.error`` must be a Python int (it sizes the kernel window): jit this
+    with the index arrays as arguments and ``error`` static, as the engines
+    do.  The three steps carry the ``fit.prelude``, ``fit.kernel`` and
+    ``fit.snap`` named scopes, the overflow fallback ``fit.bisect``."""
     # lazy: repro.kernels imports this module for its thin wrappers
     from repro.kernels.fitting_lookup import fitting_lookup_pallas
 
     plan = make_plan(int(idx.keys.shape[0]), int(idx.error))
-    nq = queries.shape[0]
     queries = queries.astype(jnp.float32)
     with jax.named_scope("fit.prelude"):
         keys_padded = pad_keys(idx.keys, plan)
-        q_b, qlo_b, src_b = _pallas_bucketize(idx, queries, plan, qcap)
+        q_b, qlo_b, slots = _pallas_bucketize(idx, queries, plan, qcap)
 
     # --- Pallas kernel over key blocks
     with jax.named_scope("fit.kernel"):
         rank_b, found_b = fitting_lookup_pallas(
             keys_padded, q_b, qlo_b, kb=plan.kb, window=plan.window)
 
-    # --- scatter back
+    # --- read back at the queries' own slots
     with jax.named_scope("fit.snap"):
-        res = jnp.full((nq,), jnp.iinfo(jnp.int32).min, jnp.int32)
-        flat_src = src_b.reshape(-1)
-        flat_ans = jnp.where(found_b.reshape(-1), rank_b.reshape(-1), -1)
-        good = flat_src >= 0
-        res = res.at[jnp.clip(flat_src, 0, None)].max(
-            jnp.where(good, flat_ans, jnp.iinfo(jnp.int32).min))
-        answered = res > jnp.iinfo(jnp.int32).min
-        res = jnp.where(answered, res, -1)
-        if fallback:
-            was_bucketed = jnp.zeros((nq,), bool).at[
-                jnp.clip(flat_src, 0, None)].max(good)
+        at = (slots.blk, slots.slot)
+        hit = found_b.at[at].get(mode="clip")
+        res = _read_back(slots, jnp.where(hit, rank_b.at[at].get(mode="clip"),
+                                          -1))
+        need = res == _UNSET                     # bucket-overflow queries
+        res = jnp.where(need, -1, res)
 
     if fallback:
-        # bucket-overflow queries (never bucketed) answered by the XLA bisect
-        # path; lax.cond skips the work entirely when nothing overflowed.
-        need = ~was_bucketed
+        # bucket-overflow queries answered by the XLA bisect path; lax.cond
+        # skips the work entirely when nothing overflowed.
         with jax.named_scope("fit.bisect"):
             fb = jax.lax.cond(jnp.any(need),
                               lambda: xla_lookup(idx, queries, "bisect"),
@@ -332,23 +348,23 @@ def pallas_search(idx: DeviceIndex, queries: jax.Array, side: str = "left", *,
                   qcap: int = 256) -> jax.Array:
     """Batched insertion-rank search via the Pallas compare-reduce kernel.
 
-    Same XLA prelude (router + interpolation + bucketing) and kernel geometry
-    as :func:`pallas_lookup`; the kernel's masked compare-reduce simply counts
-    with the side's comparison (``<`` for left, ``<=`` for right) so
-    ``rank = window_start + count`` is the searchsorted insertion rank.
-    Bucket-overflow queries fall back to the XLA bisect search; the final
-    :func:`snap_side` resolves duplicate runs extending past the window."""
+    Same XLA prelude (router + interpolation + bucketing), kernel geometry
+    and read-back at each query's bucket slot as :func:`pallas_lookup`; the
+    kernel's masked compare-reduce simply counts with the side's comparison
+    (``<`` for left, ``<=`` for right) so ``rank = window_start + count`` is
+    the searchsorted insertion rank.  Bucket-overflow queries fall back to
+    the XLA bisect search; the final :func:`snap_side` resolves duplicate
+    runs extending past the window."""
     if side not in ("left", "right"):
         raise ValueError(f"side must be 'left' or 'right', got {side!r}")
     # lazy: repro.kernels imports this module for its thin wrappers
     from repro.kernels.fitting_lookup import fitting_lookup_pallas
 
     plan = make_plan(int(idx.keys.shape[0]), int(idx.error))
-    nq = queries.shape[0]
     queries = queries.astype(jnp.float32)
     with jax.named_scope("fit.prelude"):
         keys_padded = pad_keys(idx.keys, plan)
-        q_b, qlo_b, src_b = _pallas_bucketize(idx, queries, plan, qcap)
+        q_b, qlo_b, slots = _pallas_bucketize(idx, queries, plan, qcap)
 
     with jax.named_scope("fit.kernel"):
         rank_b, _ = fitting_lookup_pallas(
@@ -356,13 +372,9 @@ def pallas_search(idx: DeviceIndex, queries: jax.Array, side: str = "left", *,
             side=side)
 
     with jax.named_scope("fit.snap"):
-        res = jnp.full((nq,), jnp.iinfo(jnp.int32).min, jnp.int32)
-        flat_src = src_b.reshape(-1)
-        flat_ans = rank_b.reshape(-1)
-        good = flat_src >= 0
-        res = res.at[jnp.clip(flat_src, 0, None)].max(
-            jnp.where(good, flat_ans, jnp.iinfo(jnp.int32).min))
-    need = res == jnp.iinfo(jnp.int32).min       # bucket-overflow queries
+        res = _read_back(slots, rank_b.at[slots.blk, slots.slot].get(
+            mode="clip"))
+    need = res == _UNSET                         # bucket-overflow queries
     with jax.named_scope("fit.bisect"):
         fb = jax.lax.cond(jnp.any(need),
                           lambda: xla_search(idx, queries, side, "bisect"),

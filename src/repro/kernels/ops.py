@@ -1,13 +1,14 @@
 """jit'd wrappers around the Pallas kernels (thin compatibility layer).
 
 ``fitting_lookup``: XLA prelude (router + interpolation + bucketing) ->
-Pallas compare-reduce kernel -> scatter-back + bisect fallback for bucket
-overflow.  The orchestration now lives once in ``repro.index.engine``
-(``pallas_lookup`` / the ``pallas`` backend of ``make_engine``); this module
-keeps the historical entry points.  Equivalent to ``ref.lookup_ref`` on every
-input (tests sweep shapes/dtypes/errors); the kernel path answers all queries
-whenever each key block starts at most QCAP windows (overflow is per-block,
-flagged, and rare for non-adversarial batches).
+Pallas compare-reduce kernel -> each query's answer read at its bucket slot
++ bisect fallback for bucket overflow.  The orchestration now lives once in
+``repro.index.engine`` (``pallas_lookup`` / the ``pallas`` backend of
+``make_engine``); this module keeps the historical entry points.
+Equivalent to ``ref.lookup_ref`` on every input (tests sweep
+shapes/dtypes/errors); the kernel path answers all queries whenever each key
+block starts at most QCAP windows (overflow is per-block, flagged, and rare
+for non-adversarial batches).
 """
 from __future__ import annotations
 

@@ -21,7 +21,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.index.device import sharded_search_a2a, sharded_search_allgather
-from repro.index.engine import _run_on_index, make_plan, pallas_search
+from repro.index.engine import (_run_on_index, make_plan, pallas_lookup,
+                                pallas_search)
 from repro.kernels.fitting_lookup import fitting_lookup_pallas
 
 N_KEYS = 1 << 26
@@ -29,6 +30,11 @@ ERROR = 64
 QCAP = 256
 N_SEGMENTS = 1 << 19
 BATCH = 4096
+# the newest shard of the latest-traffic cell: 15,424 key blocks of 256,
+# segments at the full table's density, probed by a whole 16,384-key flush
+NEWEST_KEYS = 15424 * 256
+NEWEST_SEGMENTS = 1 << 15
+FLUSH = 16384
 
 
 @pytest.fixture(scope="module")
@@ -94,6 +100,18 @@ def test_pallas_search_compiles_with_index_arguments(one_chip,
     assert "tpu_custom_call" in compiled.as_text()
     # the key column enters as an argument, not as a baked-in constant
     assert compiled.memory_analysis().argument_size_in_bytes >= 4 * N_KEYS
+
+
+def test_pallas_lookup_compiles_at_the_newest_shard(one_chip,
+                                                  no_compile_cache):
+    arrays = _index_arrays(NEWEST_KEYS, NEWEST_SEGMENTS, one_chip)
+    assert make_plan(NEWEST_KEYS, ERROR).n_blocks == 15424
+    q = _spec((FLUSH,), jnp.float32, one_chip)
+    compiled = _run_on_index.lower(
+        arrays, q, impl=pallas_lookup, error=ERROR,
+        opts=(("fallback", True), ("qcap", QCAP))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().argument_size_in_bytes >= 4 * NEWEST_KEYS
 
 
 @pytest.mark.parametrize("exchange", ["allgather", "a2a"])
