@@ -415,7 +415,8 @@ def run_cell(workload: str | dict, seed: int, seconds: float, trace: bool,
         device["busy_s"] = reduction.busy_s
         device["window_s"] = reduction.window_s
         result["breakdown"] = {"device_ops": reduction.top_ops(),
-                               "idle_gaps": reduction.idle_by_label()}
+                               "idle_gaps": reduction.idle_by_label(),
+                               "device_scopes": reduction.top_scopes()}
     result["checks"] = {k: {"value": v, "limit": lim}
                         for k, (v, lim) in checks.items()}
     return result
@@ -450,7 +451,8 @@ def _reduce_trace(log_dir: str):
         shutil.rmtree(log_dir, ignore_errors=True)
     log(f"trace: {size} bytes, reduced in {time.perf_counter() - t:.3f} s;"
         f" busy {reduction.busy_s:.6f} s of {reduction.window_s:.6f} s on "
-        f"{reduction.devices} device(s)")
+        f"{reduction.devices} device(s); device s by scope: "
+        + ", ".join(f"{n} {s:.6f}" for n, s in reduction.top_scopes()))
     return reduction
 
 

@@ -36,7 +36,7 @@ def config(name: str) -> dict:
 
 def config_file(name: str) -> dict:
     """``configs/<name>.json``, whether or not a cell of the manifest
-    uses it (a configuration left out while the program is at fault)."""
+    uses it."""
     return json.loads((BENCH_DIR / "configs" / f"{name}.json").read_text())
 
 

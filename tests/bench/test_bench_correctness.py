@@ -1,9 +1,10 @@
 """``correct`` and its failures: whole runs of a cell on the CPU at a small
 size, past the harness's look for a chip.  A sound run is correct; the
 control (the reference over bfloat16 keys in the program's place) and each
-fault planted under the timed path are not; nor is the program on 64-bit
-keys that collide in float32, while its float64 numpy tier agrees with the
-reference."""
+fault planted under the timed path are not.  A key-width loss planted at
+the program's boundary fails exactly where float32 merges keys, and the
+program's float64 numpy tier (the witness) agrees with the reference.  No
+test here depends on how the device tiers compare 64-bit keys."""
 from __future__ import annotations
 
 import pathlib
@@ -69,15 +70,49 @@ def test_control_type_is_the_first_narrower_type_that_rounds_a_key():
         harness.LowPrecisionReference(seconds, "float64")
 
 
-def test_the_64bit_fault_is_caught_and_the_witness_sides_with_the_reference():
-    cell = {"name": "weblogs-2e26.probe-uniform", "config": "weblogs-2e26",
-            "traffic": "probe-uniform", "chips": 1}
-    r = harness.run_cell(cell, SEED, 1.0, False, require_tpu=False,
-                         witness=True, overrides=dict(SMALL,
-                                                      n_keys=1 << 20))
+def _cell(config, traffic):
+    return {"name": f"{config}.{traffic}", "config": config,
+            "traffic": traffic, "chips": 1}
+
+
+def _float32(a):
+    return np.asarray(a, np.float32).astype(np.float64)
+
+
+class _Float32Program(harness.Program):
+    """The program as the harness builds it, but on the key column rounded
+    to float32 and called with every query rounded to float32."""
+
+    def __init__(self, keys, cfg, monitor, n_keys_hint):
+        super().__init__(_float32(keys), cfg, monitor, n_keys_hint)
+        lookup = self.call
+        self.call = lambda q: lookup(_float32(q))
+
+    def warm(self, keys, sampler, seed, mix):
+        return super().warm(_float32(keys), sampler, seed, mix)
+
+
+@pytest.mark.parametrize("config, keys_collide", [("weblogs-2e26", True),
+                                                  ("weblogs-194d", False)])
+def test_keys_narrowed_to_float32_fail_only_where_they_collide(
+        monkeypatch, config, keys_collide):
+    monkeypatch.setattr(harness, "Program", _Float32Program)
+    r = harness.run_cell(_cell(config, "probe-uniform"), SEED, 1.0, False,
+                         require_tpu=False,
+                         overrides=dict(SMALL, n_keys=1 << 20))
     c = checks(r)
-    assert r["correct"] is False and c["wrong_answers"] > 0
-    assert c["witness_wrong_answers"] == 0
+    assert r["correct"] is not keys_collide
+    assert (c["wrong_answers"] > 0) is keys_collide
+    assert c["unanswered_requests"] == 0
+
+
+@pytest.mark.parametrize("config, traffic", [("weblogs-2e26", "probe-uniform"),
+                                             ("maps-2e26", "probe-zipf")])
+def test_the_witness_sides_with_the_reference(config, traffic):
+    r = harness.run_cell(_cell(config, traffic), SEED, 1.0, False,
+                         require_tpu=False, witness=True,
+                         overrides=dict(SMALL, n_keys=1 << 20))
+    assert checks(r)["witness_wrong_answers"] == 0
 
 
 def _patch_lookup(monkeypatch, spoil):

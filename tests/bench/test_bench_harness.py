@@ -22,6 +22,8 @@ from bench.zipfian import Zipfian, zeta  # noqa: E402
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 TRACE = DATA / "chip_trace_v5e.json"
+SCOPED_TRACE = DATA / "chip_trace_v5e_scoped.json"
+FIT_SCOPES = {"fit.prelude", "fit.kernel", "fit.bisect", "fit.snap"}
 MS = 1_000_000
 
 
@@ -75,6 +77,104 @@ def test_recorded_chip_trace_reduces_consistently():
     assert sum(r.ops.values()) >= r.busy_s * (1 - 1e-9)
     assert len(r.gaps) > 1 and all(label for label, _ in r.gaps)
     assert len(r.top_ops()) <= 10 and len(r.idle_by_label()) <= 10
+
+
+def _load(path):
+    raw = json.loads(path.read_text())
+    return {p: {line: [tuple(e) for e in evs] for line, evs in lines.items()}
+            for p, lines in raw.items()}
+
+
+def _unscoped(tree):
+    return {p: {line: [e[:3] for e in evs] for line, evs in lines.items()}
+            for p, lines in tree.items()}
+
+
+def test_scopes_split_the_op_time_and_leave_busy_and_gaps_alone():
+    tree = _tree([("k", 0, 30, "fit.kernel"), ("s", 20, 40, "fit.snap"),
+                  ("w", 10, 60, xplane.NO_SCOPE), ("k", 50, 70, "fit.kernel"),
+                  ("p", 90, 120, "fit.prelude")])
+    r = xplane.reduce(tree)
+    plain = xplane.reduce(_unscoped(tree))
+    assert (r.busy_s, r.gaps, r.ops) == (plain.busy_s, plain.gaps, plain.ops)
+    assert r.scopes == pytest.approx({"fit.kernel": 50e-9, "fit.snap": 20e-9,
+                                      xplane.NO_SCOPE: 50e-9,
+                                      "fit.prelude": 10e-9})
+    assert sum(r.scopes.values()) == pytest.approx(sum(r.ops.values()))
+    assert plain.scopes == pytest.approx({xplane.NO_SCOPE: 130e-9})
+    assert r.top_ops(2) == [["fit.kernel|k", pytest.approx(50e-9)],
+                            [f"{xplane.NO_SCOPE}|w", pytest.approx(50e-9)]]
+    assert r.top_scopes(1) == [["fit.kernel", pytest.approx(50e-9)]]
+
+
+def test_the_innermost_fit_scope_names_an_op():
+    path = ("jit(_run_on_index)/fit.prelude/jit(searchsorted)/"
+            "jit(_run_on_index)/fit.bisect/cond/branch_1_fun/fit.prelude/"
+            "jit(searchsorted)/vmap()/while/body/closed_call/gather:")
+    assert xplane.innermost_scope(path) == "fit.prelude"
+    assert xplane.innermost_scope(
+        "jit(_run_on_index)/fit.kernel/cond/branch_0_fun/pallas_call:"
+    ) == "fit.kernel"
+    assert xplane.innermost_scope("jit(f)/while/body/gather:") == \
+        xplane.NO_SCOPE
+    assert xplane.innermost_scope("") == xplane.NO_SCOPE
+
+
+def test_planes_read_each_device_op_scope_from_its_metadata(tmp_path):
+    """An XSpace written with the protobuf classes: the scope comes from
+    the ``tf_op`` stat of each op's event metadata."""
+    pb = xplane._xplane_pb2()
+    space = pb.XSpace()
+    dev = space.planes.add(id=1, name="/device:TPU:0")
+    dev.stat_metadata[7].name = xplane.SCOPE_STAT
+    dev.stat_metadata[7].id = 7
+    paths = {1: "jit(f)/fit.kernel/pallas_call:", 2: None,
+             3: "jit(f)/fit.prelude/jit(searchsorted)/fit.bisect/gather:"}
+    for k, path in paths.items():
+        meta = dev.event_metadata[k]
+        meta.id, meta.name = k, f"%op.{k} = f32[8] op()"
+        if path is not None:
+            meta.stats.add(metadata_id=7, str_value=path)
+    line = dev.lines.add(id=1, name=xplane.OPS_LINE, timestamp_ns=1000)
+    for k, offset_ps in ((1, 0), (2, 5000), (3, 9000), (1, 20000)):
+        line.events.add(metadata_id=k, offset_ps=offset_ps,
+                        duration_ps=2000)
+    host = space.planes.add(id=2, name=xplane.HOST_PLANE)
+    host.event_metadata[1].name = xplane.WINDOW_EVENT
+    host.lines.add(id=1, name="python3", timestamp_ns=1000).events.add(
+        metadata_id=1, offset_ps=0, duration_ps=30000)
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space.SerializeToString())
+    tree = xplane.planes(str(path))
+    ops = tree["/device:TPU:0"][f"{xplane.OPS_LINE}#0"]
+    assert [(n.split()[0], s, e, sc) for n, s, e, sc in ops] == [
+        ("%op.1", 1000, 1002, "fit.kernel"),
+        ("%op.2", 1005, 1007, xplane.NO_SCOPE),
+        ("%op.3", 1009, 1011, "fit.bisect"),
+        ("%op.1", 1020, 1022, "fit.kernel")]
+    assert tree[xplane.HOST_PLANE]["python3#0"] == [
+        (xplane.WINDOW_EVENT, 1000, 1030)]
+    r = xplane.reduce(tree)
+    assert r.scopes == pytest.approx({"fit.kernel": 4e-9, "fit.bisect": 2e-9,
+                                      xplane.NO_SCOPE: 2e-9})
+
+
+def test_recorded_scoped_chip_trace_reduces_by_scope():
+    """20 ms of a traced ``weblogs194d-latest`` run recorded on one v5e, as
+    ``xplane.planes`` reads it (each device op with its scope), names cut
+    to 100 characters."""
+    tree = _load(SCOPED_TRACE)
+    r = xplane.reduce(tree)
+    plain = xplane.reduce(_unscoped(tree))
+    assert (r.window_s, r.busy_s, r.gaps, r.ops) == (
+        plain.window_s, plain.busy_s, plain.gaps, plain.ops)
+    assert r.devices == 1 and 0 < r.busy_s < r.window_s
+    assert set(r.scopes) == FIT_SCOPES | {xplane.NO_SCOPE}
+    assert sum(r.scopes.values()) == pytest.approx(sum(r.ops.values()),
+                                                   rel=1e-12)
+    assert all(name.split("|", 1)[0] in r.scopes for name, _ in r.top_ops())
+    assert [n for n, _ in r.top_scopes()] == sorted(
+        r.scopes, key=r.scopes.get, reverse=True)
 
 
 # -------------------------------------------------------------- window numbers
