@@ -99,6 +99,7 @@ INPLACE_NDARRAY_METHODS = frozenset({
 HOST_ONLY_MODULES = (
     "repro/index/table.py",
     "repro/index/query.py",
+    "repro/index/keycode.py",
     "repro/index/telemetry.py",
     "repro/core/tree.py",
     "repro/core/segmentation.py",

@@ -14,11 +14,14 @@ Module map (see ROADMAP.md):
   query.py    -- the typed query plane: ``PointResult``/``RangeResult`` and
                  the ``QueryVerbs`` mixin deriving point / range / count /
                  predecessor / successor from the one ``search`` primitive
+  keycode.py  -- the order-preserving 64-bit key code (two int32 words) and
+                 the key compares of the device tiers; numpy-only
   engine.py   -- ``LookupEngine`` registry: numpy / xla-window / xla-bisect /
                  pallas bounded-window search (point lookups *and* the
                  two-sided ``search`` rank primitive), ``DeviceIndex`` device
-                 form, and ``DispatchEngine`` (batch-size-aware tier routing
-                 with cost-model-derived default thresholds)
+                 form (float32 keys, or 64-bit codes where float32 cannot
+                 hold them), and ``DispatchEngine`` (batch-size-aware tier
+                 routing with cost-model-derived default thresholds)
   snapshot.py -- epoch publishing: Alg. 4 inserts -> ``publish()`` ->
                  ``ServingHandle`` atomic swap into serving
   sharded.py  -- ``ShardedIndexService``: N key-partitioned writers with

@@ -36,9 +36,11 @@ publishes stay delta-eligible and shape-stable (no jit retrace); cap
 overflow or a boundary change (rebalance / structural replan) falls back to
 a full re-pack with fresh headroom.
 
-All five query verbs stay bit-identical to the numpy oracle under the f32
-key contract (exact for f32-representable keys, e.g. integers < 2^24 --
-the same contract as every device backend in ``repro.index.engine``).
+All five query verbs stay bit-identical to the numpy oracle when keys and
+queries are all float32 (e.g. integers < 2^24).  The rows hold float32
+keys, so a publish of a column that needs 64 bits raises ``ValueError``
+(``_pack_row``) instead of answering wrongly; the single-chip engines in
+``repro.index.engine`` serve such columns in their wide form.
 
 Runs on CPU via ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
 (see ``tests/_device_check.py``); the collectives are the same on real
@@ -61,6 +63,7 @@ from repro.analysis import sanitizer
 from repro.core.cost_model import choose_exchange
 from repro.index.table import route_keys
 
+from . import keycode
 from .engine import DeviceIndex, xla_search
 from .query import PointResult, RangeResult, check_range, check_side
 from .sharded import ShardedIndexService
@@ -261,7 +264,14 @@ class DeviceShardSet:
 def _pack_row(table, s_cap: int, m_cap: int):
     """One shard's padded device row: +inf start-key / key padding, 0 slope,
     n_keys base/seg_end (an empty trailing window) -- the
-    ``pack_shard_tables`` scheme widened to capacity, in device dtypes."""
+    ``pack_shard_tables`` scheme widened to capacity, in device dtypes.
+    Refuses a key column that float32 cannot hold."""
+    if not keycode.f32_exact(table.keys):
+        raise ValueError(
+            "DeviceShardSet rows hold 32-bit (float32) keys, and this key "
+            "column needs 64 bits: some keys do not survive float64 -> "
+            "float32 -> float64.  Serve it on one chip (open_index without "
+            "device_count), whose engines compare 64-bit keys.")
     s, n = table.n_segments, table.n_keys
     seg_start = np.full(s_cap, np.inf, np.float32)
     slope = np.zeros(s_cap, np.float32)

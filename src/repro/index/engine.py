@@ -20,16 +20,20 @@ generalized to insertion ranks (``np.searchsorted`` semantics, with
 ``snap_side`` repairing duplicate runs that extend past the window) -- from
 which ``repro.index.query`` derives point / range / count / predecessor /
 successor uniformly across backends.
-Backends return identical ranks for any key column whose keys and queries
-are exact in f32 (e.g. integer keys < 2^24, the serving regime -- see
-rescale_keys): the ``numpy`` backend compares in f64 while the device
-backends compare in f32, so a query that is only f32-equal to a stored key
-can differ in membership across that boundary.  ``DeviceIndex`` is the f32 device form of a
-``SegmentTable`` (re-exported by repro.core.jax_index for compatibility).
+Every backend returns ``np.searchsorted``'s ranks on the float64 key column
+for any float64 query.  The ``numpy`` backend compares in float64; the device
+backends choose their key form from the data (:func:`device_index`): float32
+when every key is a float32 (the host then rounds each query toward the side
+whose count it keeps, and answers a lookup of a value float32 cannot hold
+with -1), else the keys' order-preserving 64-bit codes as two int32 words
+(``repro.index.keycode``), searched in a window widened by a slack measured
+on the table and checked at its edges.  ``DeviceIndex`` is the device form of
+a ``SegmentTable`` (re-exported by repro.core.jax_index for compatibility).
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Literal, NamedTuple, Protocol, runtime_checkable
 
 import jax
@@ -39,9 +43,12 @@ import numpy as np
 from repro.analysis.contracts import hot_path
 from repro.analysis.sanitizer import make_lock
 
+from . import keycode
+from .keycode import Wide
 from .query import QueryVerbs
 from .table import SegmentTable, numpy_lookup, numpy_search
-from .telemetry import CH_D2H, CH_H2D, CH_LAUNCH, CH_TIER_PREFIX, NO_SPAN
+from .telemetry import (CH_D2H, CH_ENCODE, CH_H2D, CH_LAUNCH, CH_TIER_PREFIX,
+                        CH_WIDEN, NO_SPAN)
 
 
 def _bucket_size(n: int) -> int:
@@ -51,54 +58,181 @@ def _bucket_size(n: int) -> int:
 
 
 class DeviceIndex(NamedTuple):
-    """f32/i32 device form of a SegmentTable (arrays VMEM/HBM friendly)."""
+    """Device form of a SegmentTable.  ``keys`` is the float32 column when
+    every key is a float32 and the float32 predictor cannot overflow
+    (:func:`_narrow_fits`), else the wide form: each key's order-preserving
+    64-bit code as two int32 words (:class:`~repro.index.keycode.Wide`,
+    8 B a key), searched in a window
+    widened by ``slack`` and checked at its edges.  Both forms route and
+    predict with the float32 segment arrays."""
     seg_start: jax.Array  # (S,) f32  first key of each segment
     slope: jax.Array      # (S,) f32
     base: jax.Array       # (S,) i32  global position of segment start
     seg_end: jax.Array    # (S,) i32  one past the segment end
-    keys: jax.Array       # (N,) f32  the sorted key column (HBM resident)
+    keys: jax.Array | Wide  # (N,) f32, or (N,) x 2 i32 words: sorted keys
     error: int            # static
+    slack: int = 0        # static: the wide form's reach beyond ``error``
+
+
+def slack_cap(error: int) -> int:
+    """The largest slack whose window still fits the float32 form's kernel
+    block (``make_plan``): 63 at error 256.  The wide form's geometry is
+    then the float32 form's; a query its predictor misses by more is
+    settled by ``fit.widen``."""
+    window = 2 * error + 2
+    return (max(128, _round_up(window, 128)) - window) // 2
+
+
+def _narrow_fits(table: SegmentTable) -> bool:
+    """Whether the float32 form answers this table exactly: every key is a
+    float32, and the float32 predictor's position stays far inside int32
+    for every query from the first key to just past the last (the host
+    moves queries beyond them there, :func:`_encode_narrow`).  A gap
+    between segments worth 2^30 ranks at its segment's slope fails it."""
+    if not keycode.f32_exact(table.keys):
+        return False
+    if table.n_keys == 0:
+        return True
+    start = table.start_key.astype(np.float32)
+    end = np.append(start[1:], np.nextafter(np.float32(table.keys[-1]),
+                                            np.float32(np.inf)))
+    with np.errstate(over="ignore", invalid="ignore"):
+        reach = (end - start) * table.slope.astype(np.float32)
+    return bool(np.all(table.base + reach < 2 ** 30))   # NaN, inf fail
+
+
+def key_slack(table: SegmentTable) -> int | None:
+    """How far the wide form's window reaches past ``error`` for this
+    table, or None when the float32 form serves it (:func:`_narrow_fits`).
+
+    The device predicts in float32, and keys that share one float32 value
+    share one prediction, so it can miss a key's rank by more than
+    ``error``.  This evaluates that predictor on the table's own keys and
+    takes its largest miss beyond ``error``, plus 1 for an absent query
+    (the predictor is monotone inside a segment, so an absent query's rank
+    is at most one past its neighbours'), capped at :func:`slack_cap`.
+    Exactness does not rest on it: the search checks every answer at its
+    window's edges."""
+    if _narrow_fits(table):
+        return None
+    n = table.n_keys
+    k = table.keys.astype(np.float32)
+    start = table.start_key.astype(np.float32)
+    sid = np.clip(np.searchsorted(start, k, side="right") - 1, 0,
+                  start.size - 1)
+    local = (k - start[sid]) * table.slope.astype(np.float32)[sid]
+    base = table.base[sid]
+    pred = base + np.round(np.clip(local, -2 ** 31, 2 ** 31 - 1)
+                           ).astype(np.int64)
+    pred = np.minimum(np.maximum(pred, base), table.seg_end[sid])
+    miss = int(np.abs(pred - np.arange(n)).max()) if n else 0
+    return min(max(0, miss - table.error) + 1, slack_cap(table.error))
 
 
 def device_index(table: SegmentTable) -> DeviceIndex:
-    """Convert (and cache on the table -- snapshots are shared by engines)."""
+    """Convert (and cache on the table -- snapshots are shared by engines).
+    The key form and the wide form's slack follow the data
+    (:func:`key_slack`)."""
     dev = getattr(table, "_device_cache", None)
     if dev is None:
         # cast on the host: a device-side cast compiles once per shape
         def put(a, dtype):
             return jax.device_put(np.asarray(a, dtype))
 
+        slack = key_slack(table)
+        if slack is None:
+            keys = put(table.keys, np.float32)
+        else:
+            code = keycode.encode(table.keys)
+            keys = Wide(put(code.hi, np.int32), put(code.lo, np.int32))
         dev = DeviceIndex(
             seg_start=put(table.start_key, np.float32),
             slope=put(table.slope, np.float32),
             base=put(table.base, np.int32),
             seg_end=put(table.seg_end, np.int32),
-            keys=put(table.keys, np.float32),
+            keys=keys,
             error=int(table.error),
+            slack=slack or 0,
         )
         object.__setattr__(table, "_device_cache", dev)  # frozen dataclass
     return dev
 
 
+class WideProbe(NamedTuple):
+    """Queries of the wide form, as the device reads them."""
+    route: jax.Array   # (nq,) f32  the query rounded: routes and predicts
+    key: Wide          # (nq,) x 2 i32  its code: compared with the keys
+    weight: jax.Array  # (nq,) i32  1 for a query, 0 for bucket padding
+
+    @classmethod
+    def unpack(cls, rows: jax.Array) -> "WideProbe":
+        """From the (4, nq) int32 block :func:`_encode_wide` sends."""
+        return cls(jax.lax.bitcast_convert_type(rows[0], jnp.float32),
+                   Wide(rows[1], rows[2]), rows[3])
+
+
+def _is_wide(idx: DeviceIndex) -> bool:
+    return isinstance(idx.keys, Wide)
+
+
+def _parts(queries) -> tuple[jax.Array, jax.Array | Wide]:
+    """``(route, key)``: what a query routes with, what it compares with."""
+    if isinstance(queries, WideProbe):
+        return queries.route, queries.key
+    return queries, queries
+
+
+def _take(keys, i):
+    """``keys[i]``, of either key form."""
+    return jax.tree.map(lambda col: col[i], keys)
+
+
+def _bisect_step(keys, queries, side: str):
+    """One step of a bisect of ``[lo, hi)``: ``lo`` moves past ``mid`` when
+    its key is below the query (``keycode.below(side)``)."""
+    n = keys.shape[0]
+    below = keycode.below(side)
+
+    def body(_, lh):
+        lo, hi = lh
+        mid = (lo + hi) // 2
+        go = below(_take(keys, jnp.minimum(mid, n - 1)), queries) & (lo < hi)
+        return jnp.where(go, mid + 1, lo), jnp.where(go, hi, mid)
+
+    return body
+
+
+def _full_search(keys, queries, side: str) -> jax.Array:
+    """``searchsorted(keys, queries, side)`` over the whole column."""
+    if not isinstance(keys, Wide):
+        return jnp.searchsorted(keys, queries, side=side)
+    n = keys.shape[0]
+    lo = jnp.zeros(queries.shape, jnp.int32)
+    hi = jnp.full(queries.shape, n, jnp.int32)
+    lo, _ = jax.lax.fori_loop(0, n.bit_length(),
+                              _bisect_step(keys, queries, side), (lo, hi))
+    return lo
+
+
 # --------------------------------------------------------------------- device
-def snap_leftmost(keys: jax.Array, queries: jax.Array, rank: jax.Array,
-                  hit: jax.Array) -> jax.Array:
+def snap_leftmost(keys, queries, rank: jax.Array, hit: jax.Array
+                  ) -> jax.Array:
     """Snap duplicate hits to the leftmost occurrence (device mirror of the
     ``numpy_lookup`` fix): when a found rank's left neighbour still equals
     the query, the duplicate run straddles a segment boundary and the
     window search returned an in-segment rank.  ``lax.cond`` skips the
     full-column bisect entirely unless some query actually needs it, so the
     duplicate-free fast path pays one extra gather."""
-    need = hit & (rank > 0) & (keys[jnp.maximum(rank - 1, 0)] == queries)
+    need = hit & (rank > 0) & keycode.eq(
+        _take(keys, jnp.maximum(rank - 1, 0)), queries)
     fixed = jax.lax.cond(
         jnp.any(need),
-        lambda: jnp.searchsorted(keys, queries, side="left").astype(rank.dtype),
+        lambda: _full_search(keys, queries, "left").astype(rank.dtype),
         lambda: rank)
     return jnp.where(need, fixed, rank)
 
 
-def snap_side(keys: jax.Array, queries: jax.Array, rank: jax.Array,
-              side: str) -> jax.Array:
+def snap_side(keys, queries, rank: jax.Array, side: str) -> jax.Array:
     """Side-generalized duplicate snap for insertion-rank searches (the
     ``search`` primitive): a bounded window parks inside a duplicate run that
     extends past it, which is detectable from the landing position alone --
@@ -108,14 +242,54 @@ def snap_side(keys: jax.Array, queries: jax.Array, rank: jax.Array,
     fast-path discipline as :func:`snap_leftmost`)."""
     n = keys.shape[0]
     if side == "left":
-        need = (rank > 0) & (keys[jnp.maximum(rank - 1, 0)] == queries)
+        need = (rank > 0) & keycode.eq(
+            _take(keys, jnp.maximum(rank - 1, 0)), queries)
     else:
-        need = (rank < n) & (keys[jnp.minimum(rank, n - 1)] == queries)
+        need = (rank < n) & keycode.eq(
+            _take(keys, jnp.minimum(rank, n - 1)), queries)
     fixed = jax.lax.cond(
         jnp.any(need),
-        lambda: jnp.searchsorted(keys, queries, side=side).astype(rank.dtype),
+        lambda: _full_search(keys, queries, side).astype(rank.dtype),
         lambda: rank)
     return jnp.where(need, fixed, rank)
+
+
+def _widen(idx: DeviceIndex, queries: WideProbe, rank: jax.Array,
+           lo: jax.Array, hi: jax.Array, side: str
+           ) -> tuple[jax.Array, jax.Array]:
+    """Settle a wide-form search of the windows ``[lo, hi)``.  ``rank`` is
+    the column's rank exactly when the keys just outside the window lie on
+    the right sides of the query: below it before ``lo``, not below it from
+    ``hi`` on.  Every other query -- one the float32 predictor missed by
+    more than the slack, a duplicate run past an edge -- is searched again
+    over the whole column, under ``lax.cond``, so a batch that needs none
+    pays two gathers.  Returns the ranks and how many queries (not padding)
+    were searched again.  The ``fit.widen`` scope."""
+    keys = idx.keys
+    n = keys.shape[0]
+    below = keycode.below(side)
+    q = queries.key
+    with jax.named_scope("fit.widen"):
+        before = _take(keys, jnp.clip(lo - 1, 0, n - 1))
+        after = _take(keys, jnp.clip(hi, 0, n - 1))
+        need = ~(((lo <= 0) | below(before, q))
+                 & ((hi >= n) | ~below(after, q)))
+        fixed = jax.lax.cond(jnp.any(need),
+                             lambda: _full_search(keys, q, side),
+                             lambda: rank)
+        return (jnp.where(need, fixed, rank),
+                jnp.sum(jnp.where(need, queries.weight, 0)))
+
+
+def _member(idx: DeviceIndex, queries: WideProbe, rank: jax.Array
+            ) -> jax.Array:
+    """A lookup's answer from the exact left insertion rank: the rank when
+    the key there is the query, else -1."""
+    n = idx.keys.shape[0]
+    with jax.named_scope("fit.snap"):
+        hit = (rank < n) & keycode.eq(
+            _take(idx.keys, jnp.minimum(rank, n - 1)), queries.key)
+        return jnp.where(hit, rank, -1)
 
 
 def predict_positions(idx: DeviceIndex, queries: jax.Array) -> jax.Array:
@@ -130,11 +304,34 @@ def predict_positions(idx: DeviceIndex, queries: jax.Array) -> jax.Array:
     return jnp.clip(pred, idx.base[sid], idx.seg_end[sid])
 
 
-def xla_lookup(idx: DeviceIndex, queries: jax.Array,
-               strategy: Literal["window", "bisect"] = "window") -> jax.Array:
+def _bisect(idx: DeviceIndex, queries, pred: jax.Array, side: str
+            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Bisect each query's window ``[lo, hi)``, ``error + slack`` keys each
+    side of its predicted position.  Returns ``(rank, lo, hi)``: ``rank`` is
+    ``lo`` plus the window's keys below the query (``keycode.below``).  The
+    ``fit.bisect`` scope."""
+    keys = idx.keys
+    n = keys.shape[0]
+    e = idx.error + idx.slack
+    steps = int(np.ceil(np.log2(2 * e + 2)))
+    with jax.named_scope("fit.bisect"):
+        lo = jnp.clip(pred - e, 0, n).astype(jnp.int32)
+        hi = jnp.clip(pred + e + 1, 0, n).astype(jnp.int32)
+        rank, _ = jax.lax.fori_loop(0, steps, _bisect_step(keys, queries, side),
+                                    (lo, hi))
+    return rank, lo, hi
+
+
+def xla_lookup(idx: DeviceIndex, queries, strategy: Literal["window", "bisect"]
+               = "window"):
     """Batched point lookup, rank or -1.  jit-safe; ``error`` is static.
     Its device steps carry the ``fit.prelude``, ``fit.bisect`` and
-    ``fit.snap`` named scopes."""
+    ``fit.snap`` named scopes.  On the wide form (a :class:`WideProbe` of
+    queries) it is the left :func:`xla_search` and a membership check, and
+    returns ``(answers, widened)``."""
+    if _is_wide(idx):
+        rank, widened = xla_search(idx, queries, "left", strategy)
+        return _member(idx, queries, rank), widened
     n = idx.keys.shape[0]
     with jax.named_scope("fit.prelude"):
         pred = predict_positions(idx, queries)
@@ -143,81 +340,61 @@ def xla_lookup(idx: DeviceIndex, queries: jax.Array,
         w = 2 * e + 2
         start = jnp.clip(pred - e, 0, jnp.maximum(n - w, 0)).astype(jnp.int32)
         offs = start[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-        vals = idx.keys[jnp.minimum(offs, n - 1)]
-        lt = (vals < queries[:, None]).sum(axis=1).astype(jnp.int32)
+        vals = _take(idx.keys, jnp.minimum(offs, n - 1))
+        lt = keycode.lt(vals, queries[:, None]).sum(axis=1).astype(jnp.int32)
         rank = start + lt
-        hit = (vals == queries[:, None]).any(axis=1)
+        hit = keycode.eq(vals, queries[:, None]).any(axis=1)
         with jax.named_scope("fit.snap"):
             rank = snap_leftmost(idx.keys, queries, rank, hit)
             return jnp.where(hit, rank, -1)
-    # bisect: lo/hi halving on the clipped window
-    steps = int(np.ceil(np.log2(2 * e + 2)))
-
-    def body(_, lh):
-        lo, hi = lh
-        mid = (lo + hi) // 2
-        v = idx.keys[jnp.minimum(mid, n - 1)]
-        go = (v < queries) & (lo < hi)
-        return jnp.where(go, mid + 1, lo), jnp.where(go, hi, mid)
-
+    lo, _, _ = _bisect(idx, queries, pred, "left")
     with jax.named_scope("fit.bisect"):
-        lo = jnp.clip(pred - e, 0, n).astype(jnp.int32)
-        hi = jnp.clip(pred + e + 1, 0, n).astype(jnp.int32)
-        lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
-        ok = (lo < n) & (idx.keys[jnp.minimum(lo, n - 1)] == queries)
+        ok = (lo < n) & keycode.eq(_take(idx.keys, jnp.minimum(lo, n - 1)),
+                                   queries)
     with jax.named_scope("fit.snap"):
         lo = snap_leftmost(idx.keys, queries, lo, ok)
         return jnp.where(ok, lo, -1)
 
 
-def xla_search(idx: DeviceIndex, queries: jax.Array, side: str = "left",
-               strategy: Literal["window", "bisect"] = "bisect") -> jax.Array:
+def xla_search(idx: DeviceIndex, queries, side: str = "left",
+               strategy: Literal["window", "bisect"] = "bisect"):
     """Batched bounded-window rank search: the device mirror of
-    :func:`repro.index.table.numpy_search` (f32 compares).  Returns the
-    insertion rank of every query -- ``searchsorted(keys, q, side)`` -- via
-    the interpolated +-error window; jit-safe, ``error``/``side``/``strategy``
-    static.
+    :func:`repro.index.table.numpy_search`.  Returns the insertion rank of
+    every query -- ``searchsorted(keys, q, side)`` -- via the interpolated
+    +-error window; jit-safe, ``error``/``side``/``strategy`` static.
 
     ``window`` counts the in-window keys strictly below (``side="left"``) or
     at-or-below (``side="right"``) each query; ``bisect`` runs log2(2e+2)
-    halving steps with the side's comparison.  Both end with
+    halving steps with the side's comparison.  The float32 form ends with
     :func:`snap_side`, so duplicate runs extending past the window still
-    resolve to the exact global rank."""
+    resolve to the exact global rank; the wide form's window reaches
+    ``error + slack`` and ends with :func:`_widen`, returning ``(ranks,
+    widened)``."""
     if side not in ("left", "right"):
         raise ValueError(f"side must be 'left' or 'right', got {side!r}")
-    n = idx.keys.shape[0]
+    keys = idx.keys
+    n = keys.shape[0]
+    route, q = _parts(queries)
     with jax.named_scope("fit.prelude"):
-        pred = predict_positions(idx, queries)
-    e = idx.error
+        pred = predict_positions(idx, route)
     if strategy == "window":
+        e = idx.error + idx.slack
         w = 2 * e + 2
         start = jnp.clip(pred - e, 0, jnp.maximum(n - w, 0)).astype(jnp.int32)
         offs = start[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
         valid = offs < n                       # clamped gathers replicate the
-        vals = idx.keys[jnp.minimum(offs, n - 1)]  # last key: mask them out
-        if side == "left":
-            cmp = vals < queries[:, None]
-        else:
-            cmp = vals <= queries[:, None]
+        vals = _take(keys, jnp.minimum(offs, n - 1))  # last key: mask them
+        cmp = keycode.below(side)(vals, jax.tree.map(lambda c: c[:, None], q))
         rank = start + (valid & cmp).sum(axis=1).astype(jnp.int32)
+        if _is_wide(idx):
+            return _widen(idx, queries, rank, start, start + w, side)
         with jax.named_scope("fit.snap"):
-            return snap_side(idx.keys, queries, rank, side)
-    steps = int(np.ceil(np.log2(2 * e + 2)))
-
-    def body(_, lh):
-        lo, hi = lh
-        mid = (lo + hi) // 2
-        v = idx.keys[jnp.minimum(mid, n - 1)]
-        ok = (v < queries) if side == "left" else (v <= queries)
-        go = ok & (lo < hi)
-        return jnp.where(go, mid + 1, lo), jnp.where(go, hi, mid)
-
-    with jax.named_scope("fit.bisect"):
-        lo = jnp.clip(pred - e, 0, n).astype(jnp.int32)
-        hi = jnp.clip(pred + e + 1, 0, n).astype(jnp.int32)
-        lo, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
+            return snap_side(keys, q, rank, side)
+    rank, lo, hi = _bisect(idx, q, pred, side)
+    if _is_wide(idx):
+        return _widen(idx, queries, rank, lo, hi, side)
     with jax.named_scope("fit.snap"):
-        return snap_side(idx.keys, queries, lo, side)
+        return snap_side(keys, q, rank, side)
 
 
 # --------------------------------------------------------------------- pallas
@@ -234,6 +411,8 @@ class LookupPlan(NamedTuple):
 
 
 def make_plan(n_keys: int, error: int) -> LookupPlan:
+    """Kernel geometry for a window of ``error`` keys each side (the wide
+    form passes ``error + slack``)."""
     # lazy: repro.kernels imports this module for its thin wrappers
     from repro.kernels.fitting_lookup import ROWS
 
@@ -244,8 +423,13 @@ def make_plan(n_keys: int, error: int) -> LookupPlan:
     return LookupPlan(kb=kb, window=window, n_blocks=n_pad // kb, n_pad=n_pad)
 
 
-def pad_keys(keys: jax.Array, plan: LookupPlan) -> jax.Array:
+def pad_keys(keys, plan: LookupPlan):
+    """The key column padded to ``plan.n_pad`` with keys above every key:
+    +inf, or ``keycode.TOP`` words for the wide form."""
     pad = plan.n_pad - keys.shape[0]
+    if isinstance(keys, Wide):
+        return jax.tree.map(
+            lambda w: jnp.pad(w, (0, pad), constant_values=keycode.TOP), keys)
     return jnp.pad(keys.astype(jnp.float32), (0, pad), constant_values=jnp.inf)
 
 
@@ -264,18 +448,21 @@ class BucketSlots(NamedTuple):
 _UNSET = np.iinfo(np.int32).min
 
 
-def _pallas_bucketize(idx: DeviceIndex, queries: jax.Array, plan: LookupPlan,
-                      qcap: int) -> tuple[jax.Array, jax.Array, BucketSlots]:
+def _pallas_bucketize(idx: DeviceIndex, queries, plan: LookupPlan,
+                      qcap: int) -> tuple:
     """The XLA prelude shared by :func:`pallas_lookup` and
     :func:`pallas_search`: router + interpolation -> window starts -> queries
     bucketed by the key block their window starts in.  Returns ``(q_b,
-    qlo_b, slots)``: per-block query values (+inf filler), global window
-    starts, and each query's :class:`BucketSlots` coordinates, which the
-    caller reads its answers back at.  A query not ``ok`` overflowed its
-    bucket and must be answered by the caller's fallback."""
-    nq = queries.shape[0]
-    pred = predict_positions(idx, queries)
-    qlo = jnp.clip(pred - idx.error, 0, plan.n_pad - plan.window).astype(jnp.int32)
+    qlo_b, qlo, slots)``: per-block query keys (filler above every key),
+    per-block and per-query global window starts, and each query's
+    :class:`BucketSlots` coordinates, which the caller reads its answers
+    back at.  A query not ``ok`` overflowed its bucket and must be answered
+    by the caller's fallback."""
+    route, q = _parts(queries)
+    nq = route.shape[0]
+    pred = predict_positions(idx, route)
+    qlo = jnp.clip(pred - (idx.error + idx.slack), 0,
+                   plan.n_pad - plan.window).astype(jnp.int32)
     blk = qlo // plan.kb                                    # owning key block
     order = jnp.argsort(blk, stable=True).astype(jnp.int32)
     blk_s = blk[order]
@@ -283,11 +470,16 @@ def _pallas_bucketize(idx: DeviceIndex, queries: jax.Array, plan: LookupPlan,
         blk_s, blk_s, side="left").astype(jnp.int32)        # rank within bucket
     ok = slot < qcap
     slot = jnp.where(ok, slot, qcap)     # overflow: off the row, so dropped
-    q_b = jnp.full((plan.n_blocks, qcap), jnp.inf, jnp.float32)
-    qlo_b = jnp.zeros((plan.n_blocks, qcap), jnp.int32)
-    q_b = q_b.at[blk_s, slot].set(queries[order], mode="drop")
+    shape = (plan.n_blocks, qcap)
+    q_b = jax.tree.map(
+        lambda w: (jnp.full(shape, jnp.inf, jnp.float32)
+                   if w.dtype == jnp.float32
+                   else jnp.full(shape, keycode.TOP, jnp.int32)), q)
+    qlo_b = jnp.zeros(shape, jnp.int32)
+    q_b = jax.tree.map(
+        lambda b, w: b.at[blk_s, slot].set(w[order], mode="drop"), q_b, q)
     qlo_b = qlo_b.at[blk_s, slot].set(qlo[order], mode="drop")
-    return q_b, qlo_b, BucketSlots(order, blk_s, slot, ok)
+    return q_b, qlo_b, qlo, BucketSlots(order, blk_s, slot, ok)
 
 
 def _read_back(slots: BucketSlots, ans: jax.Array) -> jax.Array:
@@ -298,8 +490,8 @@ def _read_back(slots: BucketSlots, ans: jax.Array) -> jax.Array:
     return jnp.zeros_like(ans).at[slots.order].set(ans, unique_indices=True)
 
 
-def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
-                  fallback: bool = True) -> jax.Array:
+def pallas_lookup(idx: DeviceIndex, queries, *, qcap: int = 256,
+                  fallback: bool = True):
     """Batched point lookup via the Pallas kernel.  Returns ranks (-1 absent).
 
     XLA prelude (router + interpolation + bucketing) -> Pallas compare-reduce
@@ -308,7 +500,13 @@ def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
     ``idx.error`` must be a Python int (it sizes the kernel window): jit this
     with the index arrays as arguments and ``error`` static, as the engines
     do.  The three steps carry the ``fit.prelude``, ``fit.kernel`` and
-    ``fit.snap`` named scopes, the overflow fallback ``fit.bisect``."""
+    ``fit.snap`` named scopes, the overflow fallback ``fit.bisect``.  On the
+    wide form it is the left :func:`pallas_search` (which always answers an
+    overflowed query) and a membership check, and returns ``(answers,
+    widened)``."""
+    if _is_wide(idx):
+        rank, widened = pallas_search(idx, queries, "left", qcap=qcap)
+        return _member(idx, queries, rank), widened
     # lazy: repro.kernels imports this module for its thin wrappers
     from repro.kernels.fitting_lookup import fitting_lookup_pallas
 
@@ -316,7 +514,7 @@ def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
     queries = queries.astype(jnp.float32)
     with jax.named_scope("fit.prelude"):
         keys_padded = pad_keys(idx.keys, plan)
-        q_b, qlo_b, slots = _pallas_bucketize(idx, queries, plan, qcap)
+        q_b, qlo_b, _, slots = _pallas_bucketize(idx, queries, plan, qcap)
 
     # --- Pallas kernel over key blocks
     with jax.named_scope("fit.kernel"):
@@ -344,8 +542,8 @@ def pallas_lookup(idx: DeviceIndex, queries: jax.Array, *, qcap: int = 256,
         return snap_leftmost(idx.keys, queries, res, res >= 0)
 
 
-def pallas_search(idx: DeviceIndex, queries: jax.Array, side: str = "left", *,
-                  qcap: int = 256) -> jax.Array:
+def pallas_search(idx: DeviceIndex, queries, side: str = "left", *,
+                  qcap: int = 256):
     """Batched insertion-rank search via the Pallas compare-reduce kernel.
 
     Same XLA prelude (router + interpolation + bucketing), kernel geometry
@@ -353,18 +551,23 @@ def pallas_search(idx: DeviceIndex, queries: jax.Array, side: str = "left", *,
     kernel's masked compare-reduce simply counts with the side's comparison
     (``<`` for left, ``<=`` for right) so ``rank = window_start + count`` is
     the searchsorted insertion rank.  Bucket-overflow queries fall back to
-    the XLA bisect search; the final :func:`snap_side` resolves duplicate
-    runs extending past the window."""
+    the XLA bisect search.  The float32 form ends with :func:`snap_side`,
+    which resolves duplicate runs extending past the window; the wide
+    form's window reaches ``error + slack``, its overflowed queries take
+    the bounded bisect, and it ends with :func:`_widen`, returning
+    ``(ranks, widened)``."""
     if side not in ("left", "right"):
         raise ValueError(f"side must be 'left' or 'right', got {side!r}")
     # lazy: repro.kernels imports this module for its thin wrappers
     from repro.kernels.fitting_lookup import fitting_lookup_pallas
 
-    plan = make_plan(int(idx.keys.shape[0]), int(idx.error))
-    queries = queries.astype(jnp.float32)
+    wide = _is_wide(idx)
+    plan = make_plan(int(idx.keys.shape[0]), int(idx.error + idx.slack))
+    if not wide:
+        queries = queries.astype(jnp.float32)
     with jax.named_scope("fit.prelude"):
         keys_padded = pad_keys(idx.keys, plan)
-        q_b, qlo_b, slots = _pallas_bucketize(idx, queries, plan, qcap)
+        q_b, qlo_b, qlo, slots = _pallas_bucketize(idx, queries, plan, qcap)
 
     with jax.named_scope("fit.kernel"):
         rank_b, _ = fitting_lookup_pallas(
@@ -375,6 +578,18 @@ def pallas_search(idx: DeviceIndex, queries: jax.Array, side: str = "left", *,
         res = _read_back(slots, rank_b.at[slots.blk, slots.slot].get(
             mode="clip"))
     need = res == _UNSET                         # bucket-overflow queries
+    if wide:
+        route, q = _parts(queries)
+        held = (res, qlo, qlo + plan.window)
+
+        def bisect_overflow():
+            got = _bisect(idx, q, predict_positions(idx, route), side)
+            return tuple(jnp.where(need, a, b) for a, b in zip(got, held))
+
+        with jax.named_scope("fit.bisect"):
+            res, lo, hi = jax.lax.cond(jnp.any(need), bisect_overflow,
+                                       lambda: held)
+        return _widen(idx, queries, res, lo, hi, side)
     with jax.named_scope("fit.bisect"):
         fb = jax.lax.cond(jnp.any(need),
                           lambda: xla_search(idx, queries, side, "bisect"),
@@ -456,15 +671,66 @@ class NumpyEngine(QueryVerbs):
         """No-op: the host path has nothing to compile."""
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "error", "opts"))
-def _run_on_index(arrays, queries, *, impl, error, opts):
+@functools.partial(jax.jit,
+                   static_argnames=("impl", "error", "opts", "slack"))
+def _run_on_index(arrays, queries, *, impl, error, opts, slack=0):
     """The one jitted entry of every device engine: ``impl(DeviceIndex,
     queries, **opts)`` with the table's arrays as *arguments*, so an
     executable serves every table of the same shapes instead of carrying the
-    key column as a constant.  ``error`` sizes the window and ``opts``
-    (sorted ``(name, value)`` pairs: strategy, side, qcap) pick the code
-    path, so both are static."""
-    return impl(DeviceIndex(*arrays, error), queries, **dict(opts))
+    key column as a constant.  ``error`` and ``slack`` size the window and
+    ``opts`` (sorted ``(name, value)`` pairs: strategy, side, qcap) pick the
+    code path, so all three are static.  On the wide form ``queries`` is
+    :func:`_encode_wide`'s block, and the answers come back followed by the
+    number of widened queries."""
+    idx = DeviceIndex(*arrays, error, slack)
+    if _is_wide(idx):
+        out, widened = impl(idx, WideProbe.unpack(queries), **dict(opts))
+        return jnp.concatenate([out, widened[None].astype(out.dtype)])
+    return impl(idx, queries, **dict(opts))
+
+
+def _encode_narrow(queries, side: str | None, span: tuple
+                   ) -> tuple[np.ndarray, np.ndarray | None]:
+    """Queries for the float32 form, where every key is a float32: each
+    query rounded to float32, toward the side whose count it keeps when
+    float32 cannot hold it (up for a left search: no key lies between it
+    and its rounding; down for a right one), then moved into ``span`` --
+    the float32 values just below the first key and just above the last,
+    which answer as anything beyond them does, and which the float32
+    predictor places without overflow.  Returns the float32 queries and a
+    mask of those the host's float64 tier answers instead, or None: NaN
+    and any query still infinite, and for a lookup (``side`` None) also
+    every query float32 cannot hold, which matches no key."""
+    x = np.asarray(queries)
+    with np.errstate(over="ignore"):    # beyond float32's range: +-inf
+        q = x.astype(np.float32)
+    off = q != x
+    if side is not None and off.any():
+        if side == "left":
+            step, toward = q < x, np.float32(np.inf)
+        else:
+            step, toward = q > x, np.float32(-np.inf)
+        with np.errstate(over="ignore"):    # float32's max steps to inf
+            q[step] = np.nextafter(q[step], toward)
+    np.clip(q, *span, out=q)
+    host = ~np.isfinite(q)
+    if side is None:
+        host |= off
+    return q, (host if host.any() else None)
+
+
+def _encode_wide(queries, size: int) -> np.ndarray:
+    """Queries for the wide form, padded to ``size`` by repeating them: one
+    (4, size) int32 block of the float32 rounding's bits (routing and
+    prediction), the two words of each query's code (``keycode.encode``),
+    and a weight of 1 for a query, 0 for padding (:class:`WideProbe`)."""
+    x = np.asarray(queries, np.float64).ravel()
+    padded = np.resize(x, size)
+    code = keycode.encode(padded)
+    weight = (np.arange(size) < x.size).astype(np.int32)
+    with np.errstate(over="ignore"):    # beyond float32's range: +-inf
+        route = padded.astype(np.float32)
+    return np.stack([route.view(np.int32), code.hi, code.lo, weight])
 
 
 class _DeviceEngine(QueryVerbs):
@@ -478,9 +744,11 @@ class _DeviceEngine(QueryVerbs):
     size would be a fresh compile.
 
     ``monitor`` (a ``repro.index.telemetry.Monitor``) splits each call into
-    three spans: ``engine.h2d`` (bucket padding and the host-to-device
-    copy), ``engine.launch`` (the jitted call until it returns) and
-    ``engine.d2h`` (the blocking read of the answer)."""
+    three spans: ``engine.h2d`` (the queries put in the key form and padded
+    -- its child span ``engine.encode`` -- then the host-to-device copy),
+    ``engine.launch`` (the jitted call until it returns) and ``engine.d2h``
+    (the blocking read of the answer).  A call on the wide form also records
+    its widened queries on ``engine.widen``."""
 
     _lookup_impl: Callable
     _search_impl: Callable
@@ -490,31 +758,56 @@ class _DeviceEngine(QueryVerbs):
         self.table = table
         self.index = device_index(table)
         self.monitor = monitor
+        self._wide = _is_wide(self.index)
+        if table.n_keys and not self._wide:   # see _encode_narrow
+            ends = np.asarray(table.keys[[0, -1]], np.float32)
+            with np.errstate(over="ignore"):
+                self._span = tuple(np.nextafter(ends, np.float32([-np.inf,
+                                                                  np.inf])))
         self._arrays = tuple(self.index)[:5]
         self._lookup_opts = tuple(sorted((lookup_opts or {}).items()))
         self._search_opts = dict(search_opts or {})
 
-    def _run(self, impl, queries, opts) -> np.ndarray:
+    def _run(self, impl, queries, opts, side: str | None) -> np.ndarray:
+        """Answer ``queries`` with ``impl``: a lookup when ``side`` is None,
+        else a search of that side."""
         mon = self.monitor
         with (NO_SPAN if mon is None else mon.span(CH_H2D)):
-            q = np.asarray(queries, np.float32)
-            flat = q.ravel()
-            n = flat.size
-            if n:
-                flat = np.resize(flat, _bucket_size(n))
-            dq = jnp.asarray(flat)
+            with (NO_SPAN if mon is None else mon.span(CH_ENCODE)):
+                shape = np.shape(queries)
+                n = math.prod(shape)
+                size = _bucket_size(n) if n else 0
+                host = None
+                if self._wide:
+                    q = _encode_wide(queries, size)
+                else:
+                    q, host = _encode_narrow(queries, side, self._span)
+                    q = q.ravel()
+                    if n:
+                        q = np.resize(q, size)
+            dq = jnp.asarray(q)
         with (NO_SPAN if mon is None else mon.span(CH_LAUNCH)):
             out = _run_on_index(self._arrays, dq, impl=impl,
-                                error=self.index.error, opts=opts)
+                                error=self.index.error,
+                                slack=self.index.slack, opts=opts)
         with (NO_SPAN if mon is None else mon.span(CH_D2H)):
             out = np.asarray(out)
-        return out[:n].reshape(q.shape)
+        if self._wide and mon is not None:
+            mon.record(CH_WIDEN, int(out[-1]))
+        out = out[:n].reshape(shape)
+        if host is not None:           # the float64 column's own answers
+            x = np.asarray(queries, np.float64)[host]
+            out = out.copy()
+            out[host] = (numpy_lookup(self.table, x) if side is None
+                         else numpy_search(self.table, x, side))
+        return out
 
     def lookup(self, queries) -> np.ndarray:
         if self.table.n_keys == 0:   # gathers on a 0-length device array are
             q = np.asarray(queries)  # undefined; an empty table always misses
             return np.full(q.shape, -1, np.int64)
-        return self._run(type(self)._lookup_impl, queries, self._lookup_opts)
+        return self._run(type(self)._lookup_impl, queries, self._lookup_opts,
+                         None)
 
     def search(self, queries, side: str = "left") -> np.ndarray:
         if side not in ("left", "right"):
@@ -522,7 +815,7 @@ class _DeviceEngine(QueryVerbs):
         if self.table.n_keys == 0:   # empty table: every rank is 0
             return np.zeros(np.asarray(queries).shape, np.int64)
         opts = tuple(sorted({**self._search_opts, "side": side}.items()))
-        out = self._run(type(self)._search_impl, queries, opts)
+        out = self._run(type(self)._search_impl, queries, opts, side)
         return out.astype(np.int64)
 
     def prewarm(self, batch_sizes=None) -> None:
@@ -586,8 +879,9 @@ class DispatchEngine(QueryVerbs):
 
     Tier engines are built lazily on first use and cached for the lifetime of
     this engine (i.e. of the snapshot), so a serving handle swap retires them
-    together with the table.  Every tier returns identical ranks for exact-f32
-    workloads (see the module docstring), so dispatch is semantics-preserving.
+    together with the table.  Every tier returns ``np.searchsorted``'s ranks
+    on the float64 key column for any float64 query (see the module
+    docstring), so dispatch is semantics-preserving.
 
     ``small_max``/``large_min`` default to ``None``: the thresholds are then
     derived from the Sec. 6 cost model for *this table's* error and segment
@@ -674,8 +968,8 @@ class DispatchEngine(QueryVerbs):
     @hot_path
     def search(self, queries, side: str = "left") -> np.ndarray:
         """The query plane's primitive, routed by batch size exactly like
-        ``lookup`` (every tier returns identical insertion ranks for exact-f32
-        workloads, so dispatch stays semantics-preserving)."""
+        ``lookup`` (every tier returns ``np.searchsorted``'s insertion ranks,
+        so dispatch stays semantics-preserving)."""
         n = int(np.size(queries))
         eng = self.engine_for(n)
         mon = self.monitor

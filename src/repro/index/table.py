@@ -173,34 +173,48 @@ class SegmentTable:
         return float(np.max(np.abs(pred - true)))
 
 
+def _windowed_rank(table: SegmentTable, q: np.ndarray, side: str
+                   ) -> np.ndarray:
+    """``searchsorted(table.keys, q, side)`` by a bounded bisect of each
+    query's window (interpolate, then log2(2*err) halving steps), checked at
+    the window's edges.  The window holds the rank when the key before it
+    is below the query and the key after it is not (``<`` for "left",
+    ``<=`` for "right"); any other query is searched again over the whole
+    column: a duplicate run straddling the routed segment or longer than
+    the window, or a query the prediction cannot place (+-inf, NaN)."""
+    keys = table.keys
+    n = keys.shape[0]
+    below = np.less if side == "left" else np.less_equal
+    start, end = table.window(q)
+    lo, hi = start, end
+    steps = max(1, math.ceil(math.log2(2 * table.error + 2)))
+    for _ in range(steps):
+        mid = (lo + hi) // 2
+        go_right = below(keys[np.minimum(mid, n - 1)], q) & (lo < hi)
+        lo = np.where(go_right, mid + 1, lo)
+        hi = np.where(go_right, hi, mid)
+    fix = ~(((start <= 0) | below(keys[np.maximum(start - 1, 0)], q))
+            & ((end >= n) | ~below(keys[np.minimum(end, n - 1)], q)))
+    if np.any(fix):
+        hits = np.flatnonzero(fix)      # search only the queries that need it
+        lo = lo.copy()
+        lo.flat[hits] = np.searchsorted(keys, q.flat[hits], side=side)
+    return lo
+
+
 def numpy_lookup(table: SegmentTable, queries) -> np.ndarray:
     """Host bounded bisect over the f64 key column (the ``numpy`` engine
-    backend and the tree's batch path): interpolate then log2(2*err) halving
-    steps inside the window.  Returns global ranks -- the *leftmost*
-    occurrence for duplicated keys -- and -1 if absent."""
+    backend and the tree's batch path): the left rank of
+    :func:`numpy_search`'s window search, kept where the key there is the
+    query.  Returns global ranks -- the *leftmost* occurrence for
+    duplicated keys -- and -1 if absent."""
     q = np.asarray(queries, np.float64)
     keys = table.keys
     n = keys.shape[0]
     if n == 0:                      # empty table: every probe misses
         return np.full(q.shape, -1, np.int64)
-    lo, hi = table.window(q)
-    steps = max(1, math.ceil(math.log2(2 * table.error + 2)))
-    for _ in range(steps):
-        mid = (lo + hi) // 2
-        mid_c = np.minimum(mid, max(n - 1, 0))
-        go_right = (keys[mid_c] < q) & (lo < hi)
-        lo = np.where(go_right, mid + 1, lo)
-        hi = np.where(go_right, hi, mid)
-    ok = (lo < n) & (keys[np.minimum(lo, max(n - 1, 0))] == q)
-    # a duplicate run straddling a segment boundary clamps the window to the
-    # routed (rightmost) segment, so the bisect lands on the in-segment
-    # leftmost; snap such hits to the global leftmost occurrence (rare: only
-    # when the left neighbour is also equal to the query)
-    fix = ok & (lo > 0) & (keys[np.maximum(lo - 1, 0)] == q)
-    if np.any(fix):
-        hits = np.flatnonzero(fix)      # bisect only the queries that need it
-        lo = lo.copy()
-        lo.flat[hits] = np.searchsorted(keys, q.flat[hits], side="left")
+    lo = _windowed_rank(table, q, "left")
+    ok = (lo < n) & (keys[np.minimum(lo, n - 1)] == q)
     return np.where(ok, lo, -1).astype(np.int64)
 
 
@@ -218,11 +232,10 @@ def numpy_search(table: SegmentTable, queries, side: str = "left") -> np.ndarray
 
     The +-error window only bounds ranks of *in-window* insertion points; a
     duplicate run straddling the routed segment (or longer than the window)
-    parks the bounded result inside the run, which the side-specific snap at
-    the end detects (left: the left neighbour still equals q; right: the
-    landing key itself still equals q) and repairs with a full ``searchsorted``
-    over just the flagged queries -- the generalization of the
-    ``numpy_lookup`` leftmost fix to both sides.
+    parks the bounded result inside the run, and a non-finite query has no
+    window at all.  The check at the window's edges (:func:`_windowed_rank`)
+    detects both and repairs them with a full ``searchsorted`` over just
+    the flagged queries.
     """
     if side not in ("left", "right"):
         raise ValueError(f"side must be 'left' or 'right', got {side!r}")
@@ -237,26 +250,7 @@ def numpy_search(table: SegmentTable, queries, side: str = "left") -> np.ndarray
         # iterations below ever could in numpy dispatch overhead alone;
         # same contract, so the window path stays the batch implementation
         return np.searchsorted(keys, q, side=side).astype(np.int64)
-    lo, hi = table.window(q)
-    steps = max(1, math.ceil(math.log2(2 * table.error + 2)))
-    for _ in range(steps):
-        mid = (lo + hi) // 2
-        mid_c = np.minimum(mid, max(n - 1, 0))
-        if side == "left":
-            go_right = (keys[mid_c] < q) & (lo < hi)
-        else:
-            go_right = (keys[mid_c] <= q) & (lo < hi)
-        lo = np.where(go_right, mid + 1, lo)
-        hi = np.where(go_right, hi, mid)
-    if side == "left":
-        fix = (lo > 0) & (keys[np.maximum(lo - 1, 0)] == q)
-    else:
-        fix = (lo < n) & (keys[np.minimum(lo, n - 1)] == q)
-    if np.any(fix):
-        hits = np.flatnonzero(fix)
-        lo = lo.copy()
-        lo.flat[hits] = np.searchsorted(keys, q.flat[hits], side=side)
-    return lo.astype(np.int64)
+    return _windowed_rank(table, q, side).astype(np.int64)
 
 
 def shard_cut_indices(keys: np.ndarray, n_shards: int) -> np.ndarray:

@@ -20,7 +20,10 @@ planner (``repro.index.fit``) leaves open:
                             served.keys (query samples)
       DispatchEngine        tier.<small|medium|large> span (batch_size,
                             wall_ns)
-      device tier engines   engine.h2d / engine.launch / engine.d2h spans
+      device tier engines   engine.h2d (with its child engine.encode) /
+                            engine.launch / engine.d2h spans; engine.widen
+                            (queries searched past the window, per call
+                            on 64-bit keys)
 
   A span (:meth:`Monitor.span`) is one timed step: its row is the caller's
   attribute columns followed by ``SPAN_COLUMNS`` -- start and duration on
@@ -88,9 +91,11 @@ CH_WAIT = "pipeline.wait"           # span per request: enqueue -> its flush
 CH_IDLE = "pipeline.idle"           # host event: the flusher awaits work
 CH_ROUTE = "shard.route"            # span: route keys, split per shard
 CH_MERGE = "shard.merge"            # span: lift local ranks, write answers
-CH_H2D = "engine.h2d"               # span: bucket pad + host-to-device
+CH_H2D = "engine.h2d"               # span: encode + host-to-device
+CH_ENCODE = "engine.encode"         # span (in engine.h2d): key form + pad
 CH_LAUNCH = "engine.launch"         # span: the jitted call until it returns
 CH_D2H = "engine.d2h"               # span: blocking read of the answer
+CH_WIDEN = "engine.widen"           # (widened,) per wide-form engine call
 CH_REPLAN = "replan"                # (applied, win, small_max, large_min,
                                     #  n_shards)
 CH_MEMTABLE = "lsm.memtable"        # (keys, tombstones, capacity) occupancy

@@ -36,66 +36,86 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.index import keycode
+from repro.index.keycode import Wide
+
 from .platform import pallas_call
 
 ROWS = 8       # buckets per grid step: one f32 sublane tile
 LANES = 128    # keys per compare chunk: one lane tile
 
 
-def _lookup_kernel(keys_a_ref, keys_b_ref, q_ref, qlo_ref,
-                   rank_ref, found_ref, *, kb: int, window: int,
-                   side: str = "left"):
+def _word(planes):
+    """One key (or query) value from its planes: the float32 value, or the
+    two words of a 64-bit code (``repro.index.keycode.Wide``)."""
+    planes = tuple(planes)
+    return Wide(*planes) if len(planes) == 2 else planes[0]
+
+
+def _lookup_kernel(*refs, kb: int, window: int, side: str = "left",
+                   planes: int = 1):
+    # refs: the key rows (one ref per plane), the next key rows, the
+    # queries (one per plane), the window starts, then the two outputs
+    keys_a = refs[:planes]
+    keys_b = refs[planes:2 * planes]
+    q_refs = refs[2 * planes:3 * planes]
+    qlo_ref, rank_ref, found_ref = refs[3 * planes:]
+    # side is static: "left" counts keys < q (rank of the first key >= q),
+    # "right" counts keys <= q (one past the last key <= q) -- the same
+    # masked compare-reduce serves point lookups and both search sides
+    below = keycode.below(side)
     g = pl.program_id(0)
     chunks = kb // LANES
     for r in range(ROWS):
-        q = q_ref[pl.ds(r, 1), :]                                     # (1, QCAP)
+        q = _word(ref[pl.ds(r, 1), :] for ref in q_refs)              # (1, QCAP)
         qlo = qlo_ref[pl.ds(r, 1), :]                                 # (1, QCAP)
         base = (g * ROWS + r) * kb               # global index of key block r
         # the window spans this bucket's key row and the next one; the next
         # row of the last bucket in the step is row 0 of the following step
-        halves = ((keys_a_ref, r, 0),
-                  (keys_a_ref, r + 1, kb) if r + 1 < ROWS else
-                  (keys_b_ref, 0, kb))
+        halves = ((keys_a, r, 0),
+                  (keys_a, r + 1, kb) if r + 1 < ROWS else
+                  (keys_b, 0, kb))
 
-        def count(c, acc, ref, row, off):
+        def count(c, acc, key_refs, row, off):
             cnt, eq = acc
             start = pl.multiple_of(c * LANES, LANES)
-            col = ref[pl.ds(row, 1), pl.ds(start, LANES)].T           # (128, 1)
+            col = _word(ref[pl.ds(row, 1), pl.ds(start, LANES)].T     # (128, 1)
+                        for ref in key_refs)
             j = base + off + start + jax.lax.broadcasted_iota(
                 jnp.int32, (LANES, 1), 0)
             in_win = (j >= qlo) & (j < qlo + window)                  # (128, QCAP)
-            # side is static: "left" counts keys < q (rank of the first key
-            # >= q), "right" counts keys <= q (one past the last key <= q) --
-            # the same masked compare-reduce serves point lookups and both
-            # search sides
-            below = (col < q) if side == "left" else (col <= q)
-            cnt = cnt + jnp.sum((in_win & below).astype(jnp.int32), axis=0,
-                                keepdims=True)
-            eq = jnp.maximum(eq, jnp.max((in_win & (col == q)).astype(
+            cnt = cnt + jnp.sum((in_win & below(col, q)).astype(jnp.int32),
+                                axis=0, keepdims=True)
+            eq = jnp.maximum(eq, jnp.max((in_win & keycode.eq(col, q)).astype(
                 jnp.int32), axis=0, keepdims=True))
             return cnt, eq
 
         acc = (jnp.zeros_like(qlo), jnp.zeros_like(qlo))
-        for ref, row, off in halves:
+        for key_refs, row, off in halves:
             acc = jax.lax.fori_loop(
-                0, chunks, functools.partial(count, ref=ref, row=row, off=off),
+                0, chunks,
+                functools.partial(count, key_refs=key_refs, row=row, off=off),
                 acc)
         rank_ref[pl.ds(r, 1), :] = qlo + acc[0]
         found_ref[pl.ds(r, 1), :] = acc[1]
 
 
-def fitting_lookup_pallas(keys_padded: jax.Array, q_bucketed: jax.Array,
-                          qlo_bucketed: jax.Array, *, kb: int, window: int,
-                          side: str = "left") -> tuple[jax.Array, jax.Array]:
+def fitting_lookup_pallas(keys_padded, q_bucketed, qlo_bucketed: jax.Array,
+                          *, kb: int, window: int, side: str = "left"
+                          ) -> tuple[jax.Array, jax.Array]:
     """Run the kernel over all key blocks.
 
     Args:
-      keys_padded:  (n_blocks*KB,) f32, padded with +inf.
-      q_bucketed:   (n_blocks, QCAP) f32 queries (+inf padding).
+      keys_padded:  (n_blocks*KB,) f32, padded with +inf; or a ``Wide`` pair
+                    of (n_blocks*KB,) i32 code words, padded with
+                    ``keycode.TOP`` (the kernel then compares the codes
+                    lexicographically, high word first).
+      q_bucketed:   (n_blocks, QCAP) queries of the keys' form (filler
+                    above every key).
       qlo_bucketed: (n_blocks, QCAP) i32 global window starts
                     (must satisfy qlo // KB == block row).
       kb:           key block size (multiple of 128, >= window).
-      window:       2*error + 2.
+      window:       the window's length: 2*error + 2.
       side:         "left" counts keys < q (point lookups and left search),
                     "right" counts keys <= q (right search); static.
     ``n_blocks`` must be a multiple of 8 and ``QCAP`` of 128
@@ -106,28 +126,34 @@ def fitting_lookup_pallas(keys_padded: jax.Array, q_bucketed: jax.Array,
              window; the wrapper's snap repairs straddling duplicate runs).
       found: (n_blocks, QCAP) bool.
     """
-    n_blocks, qcap = q_bucketed.shape
-    assert keys_padded.shape[0] == n_blocks * kb
+    keys = tuple(keys_padded) if isinstance(keys_padded, Wide) \
+        else (keys_padded,)
+    queries = tuple(q_bucketed) if isinstance(q_bucketed, Wide) \
+        else (q_bucketed,)
+    planes = len(keys)
+    n_blocks, qcap = queries[0].shape
+    assert keys[0].shape[0] == n_blocks * kb
     assert window <= kb and kb % LANES == 0, (window, kb)
     assert n_blocks % ROWS == 0 and qcap % LANES == 0, (n_blocks, qcap)
     last = n_blocks // ROWS - 1
 
     grid_spec = pl.GridSpec(
         grid=(n_blocks // ROWS,),
-        in_specs=[
-            pl.BlockSpec((ROWS, kb), lambda g: (g, 0)),              # key rows
-            pl.BlockSpec((ROWS, kb),                                 # next rows
-                         lambda g: (jnp.minimum(g + 1, last), 0)),
-            pl.BlockSpec((ROWS, qcap), lambda g: (g, 0)),            # queries
-            pl.BlockSpec((ROWS, qcap), lambda g: (g, 0)),            # starts
-        ],
+        in_specs=(
+            [pl.BlockSpec((ROWS, kb), lambda g: (g, 0))] * planes    # key rows
+            + [pl.BlockSpec((ROWS, kb),                              # next rows
+                            lambda g: (jnp.minimum(g + 1, last), 0))] * planes
+            + [pl.BlockSpec((ROWS, qcap), lambda g: (g, 0))] * planes  # queries
+            + [pl.BlockSpec((ROWS, qcap), lambda g: (g, 0))]           # starts
+        ),
         out_specs=[
             pl.BlockSpec((ROWS, qcap), lambda g: (g, 0)),
             pl.BlockSpec((ROWS, qcap), lambda g: (g, 0)),
         ],
     )
-    kernel = functools.partial(_lookup_kernel, kb=kb, window=window, side=side)
-    keys2d = keys_padded.reshape(n_blocks, kb)
+    kernel = functools.partial(_lookup_kernel, kb=kb, window=window,
+                               side=side, planes=planes)
+    keys2d = [k.reshape(n_blocks, kb) for k in keys]
     rank, found = pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -135,5 +161,5 @@ def fitting_lookup_pallas(keys_padded: jax.Array, q_bucketed: jax.Array,
             jax.ShapeDtypeStruct((n_blocks, qcap), jnp.int32),
             jax.ShapeDtypeStruct((n_blocks, qcap), jnp.int32),
         ],
-    )(keys2d, keys2d, q_bucketed, qlo_bucketed)
+    )(*keys2d, *keys2d, *queries, qlo_bucketed)
     return rank, found.astype(jnp.bool_)

@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.index.device import sharded_search_a2a, sharded_search_allgather
 from repro.index.engine import (_run_on_index, make_plan, pallas_lookup,
-                                pallas_search)
+                                pallas_search, slack_cap, xla_lookup)
+from repro.index.keycode import Wide
 from repro.kernels.fitting_lookup import fitting_lookup_pallas
 
 N_KEYS = 1 << 26
@@ -35,6 +36,10 @@ BATCH = 4096
 NEWEST_KEYS = 15424 * 256
 NEWEST_SEGMENTS = 1 << 15
 FLUSH = 16384
+# the Maps cell's 64-bit keys (bench/configs/maps-2e26.json), at the
+# widest window a table of them can ask for
+WIDE_ERROR = 256
+WIDE_SLACK = slack_cap(WIDE_ERROR)
 
 
 @pytest.fixture(scope="module")
@@ -112,6 +117,27 @@ def test_pallas_lookup_compiles_at_the_newest_shard(one_chip,
         opts=(("fallback", True), ("qcap", QCAP))).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().argument_size_in_bytes >= 4 * NEWEST_KEYS
+
+
+@pytest.mark.parametrize("impl, opts", [
+    (pallas_lookup, (("fallback", True), ("qcap", QCAP))),
+    (xla_lookup, (("strategy", "bisect"),))], ids=["pallas", "xla-bisect"])
+def test_wide_lookup_compiles_at_maps_size(one_chip, no_compile_cache, impl,
+                                           opts):
+    """The 64-bit key form of the Maps cell: 2^26 keys as two int32 code
+    words, error 256 widened by the largest slack (kb stays 640), a flush's
+    queries as one (4, nq) block."""
+    assert make_plan(N_KEYS, WIDE_ERROR + WIDE_SLACK).kb == 640
+    arrays = _index_arrays(N_KEYS, N_SEGMENTS, one_chip)
+    words = _spec((N_KEYS,), jnp.int32, one_chip)
+    arrays = (*arrays[:4], Wide(words, words))
+    q = _spec((4, FLUSH), jnp.int32, one_chip)
+    compiled = _run_on_index.lower(
+        arrays, q, impl=impl, error=WIDE_ERROR, slack=WIDE_SLACK,
+        opts=opts).compile()
+    if impl is pallas_lookup:
+        assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().argument_size_in_bytes >= 8 * N_KEYS
 
 
 @pytest.mark.parametrize("exchange", ["allgather", "a2a"])
